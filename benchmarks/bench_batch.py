@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import timed
+from repro.compile_cache import use_compile_cache
 from repro.core import to_ell_in
 from repro.core.static_engine import run_phased_static, run_phased_static_batch
 from repro.graphs import uniform_gnp
@@ -77,4 +78,5 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    use_compile_cache()
     run(a.n, a.deg, tuple(a.batches), a.seed, a.out)

@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import timed
+from repro.compile_cache import use_compile_cache
 from repro.core import dijkstra_numpy, run_delta_stepping
 from repro.core.graph import to_ell_in, to_ell_out
 from repro.core.static_engine import run_phased_static
@@ -152,4 +153,5 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_criteria.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.sources, a.seed, a.out)

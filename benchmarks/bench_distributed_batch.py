@@ -2,8 +2,9 @@
 
 Answers the tentpole question of DESIGN.md Sec. 7: how much of the
 per-phase synchronisation cost of the sharded engine does lane-batching
-amortise? For each B, the same Q-query workload runs against the
-forced-8-device CPU mesh two ways:
+amortise? For each B, the same Q-query workload runs against a mesh over
+every device JAX finds (the forced-8-device mesh under
+``JAX_PLATFORMS=cpu``) two ways:
 
   * **B=1 loop** — one ``step_sharded_batch`` drain per query (the
     pre-refactor serving pattern: every query pays every phase's collective
@@ -21,16 +22,19 @@ perf-trajectory artifact (schema ``bench_distributed/v1``).
         [--n 1024] [--queries 16] [--lanes 1 4 8] [--seed 0]
         [--out BENCH_distributed.json]
 
-The 8 fake host devices are created by this script itself (XLA_FLAGS is set
-before jax is imported), so run it in a fresh process.
+Under ``JAX_PLATFORMS=cpu`` the 8 fake host devices are created by this
+script itself (XLA_FLAGS is set before jax is imported), so run it in a
+fresh process.
 """
 from __future__ import annotations
 
 import os
 
 _FLAG = "--xla_force_host_platform_device_count=8"
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
+_XLA_FLAGS = os.environ.get("XLA_FLAGS", "")
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count" not in _XLA_FLAGS):
+    os.environ["XLA_FLAGS"] = (_XLA_FLAGS + " " + _FLAG).strip()
 
 import argparse
 import json
@@ -38,6 +42,7 @@ import json
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.distributed import (
     harvest_sharded,
     init_sharded_batch_state,
@@ -46,6 +51,7 @@ from repro.core.distributed import (
     step_sharded_batch,
 )
 from repro.graphs import grid_road
+from repro.launch.mesh import make_host_mesh
 from repro.obs.timer import now
 
 SCHEDULES = ("allreduce", "reduce_scatter")
@@ -73,17 +79,18 @@ def run_batched(sg, mesh, axes, schedule, sources, b, cap):
 
 def run(n: int = 1024, queries: int = 16, lanes=(1, 4, 8), seed: int = 0,
         out_json: str | None = "BENCH_distributed.json"):
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(tp=2)
     axes = ("data", "model")
     side = max(2, int(np.sqrt(n)))
     g = grid_road(side, side, seed=seed)
-    sg = shard_graph_batch(g, 8)
+    sg = shard_graph_batch(g, mesh.size)
     rng = np.random.default_rng(seed + 1)
     sources = rng.integers(0, g.n, queries).astype(np.int32)
     cap = g.n + 1
     print(f"graph: road grid {side}x{side} (n={g.n}, n_pad={sg.n_pad}), "
-          f"mesh (4,2) on {jax.device_count()} {jax.default_backend()} "
-          f"devices, {queries} queries, B in {list(lanes)}")
+          f"mesh {tuple(mesh.devices.shape)} on {jax.device_count()} "
+          f"{jax.default_backend()} devices, {queries} queries, "
+          f"B in {list(lanes)}")
 
     lanes = sorted(set(lanes))  # baseline is the smallest B; run it first
     results = []
@@ -123,7 +130,8 @@ def run(n: int = 1024, queries: int = 16, lanes=(1, 4, 8), seed: int = 0,
     report = {
         "schema": "bench_distributed/v1",
         "config": {"n": g.n, "n_pad": sg.n_pad, "queries": queries,
-                   "lanes_swept": list(lanes), "mesh": [4, 2], "seed": seed,
+                   "lanes_swept": list(lanes),
+                   "mesh": list(mesh.devices.shape), "seed": seed,
                    "backend": jax.default_backend(),
                    "devices": jax.device_count()},
         "results": results,
@@ -143,4 +151,5 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_distributed.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.n, a.queries, tuple(a.lanes), a.seed, a.out)

@@ -5,6 +5,7 @@ import argparse
 import json
 
 from benchmarks.common import CRITERIA, bucket_edges, fit_power, mean_phases
+from repro.compile_cache import use_compile_cache
 from repro.graphs import kronecker, uniform_gnp
 
 
@@ -73,4 +74,5 @@ if __name__ == "__main__":
     ap.add_argument("--seeds", type=int, default=5)
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    use_compile_cache()
     run(a.full, a.seeds, a.out)

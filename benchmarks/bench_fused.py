@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.obs.timer import now
 
 from repro.core import criteria as C
@@ -191,8 +192,9 @@ import numpy as np, jax
 from repro.core import run_phased
 from repro.core.distributed import run_sharded_batch
 from repro.graphs import uniform_gnp
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(tp=2)
 g = uniform_gnp(180, 8 / 180, seed=5)
 srcs = np.asarray([3, 0, 91, 179], np.int32)
 for crit in ("instatic|outstatic", "in|out"):
@@ -216,6 +218,8 @@ def _sharded_parity():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
     env.pop("XLA_FLAGS", None)
+    # the parent may hold the accelerator: the child's fake devices are CPU
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SHARDED-FUSED-PARITY-PASS" in out.stdout, out.stdout + out.stderr
@@ -306,4 +310,5 @@ if __name__ == "__main__":
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--out", default="BENCH_fused.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.reps, a.out)

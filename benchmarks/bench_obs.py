@@ -37,6 +37,7 @@ import tempfile
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.graph import to_ell_in, to_ell_in_sliced
 from repro.core.oracle import dijkstra_numpy
 from repro.core.static_engine import (
@@ -279,4 +280,5 @@ if __name__ == "__main__":
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--out", default="BENCH_obs.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.reps, a.out)

@@ -32,6 +32,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.graph import from_coo
 from repro.core.static_engine import run_phased_static
 from repro.graphs import kronecker, uniform_gnp
@@ -287,4 +288,5 @@ if __name__ == "__main__":
                     help="CI smoke sizes (n~256) instead of n~2048")
     ap.add_argument("--out", default="BENCH_p2p.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.out)

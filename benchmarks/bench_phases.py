@@ -11,6 +11,7 @@ import json
 import numpy as np
 
 from benchmarks.common import CRITERIA, bucket_edges, fit_log, fit_power, mean_phases
+from repro.compile_cache import use_compile_cache
 from repro.graphs import kronecker, uniform_gnp
 
 
@@ -72,4 +73,5 @@ if __name__ == "__main__":
     ap.add_argument("--seeds", type=int, default=5)
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    use_compile_cache()
     run(a.full, a.seeds, a.out)

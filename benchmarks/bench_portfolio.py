@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import timed
+from repro.compile_cache import use_compile_cache
 from repro.core.delta_stepping import run_delta
 from repro.core.static_engine import run_phased_static_batch
 from repro.graphs import kronecker, uniform_gnp
@@ -238,4 +239,5 @@ if __name__ == "__main__":
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--out", default="BENCH_portfolio.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.reps, a.out)

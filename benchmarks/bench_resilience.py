@@ -37,6 +37,7 @@ import zlib
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.static_engine import run_phased_static
 from repro.graphs import uniform_gnp
 from repro.obs.timer import now
@@ -283,4 +284,5 @@ if __name__ == "__main__":
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--out", default="BENCH_resilience.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.tiny, a.reps, a.out)

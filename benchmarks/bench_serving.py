@@ -39,6 +39,7 @@ import json
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import to_ell_in
 from repro.core.static_engine import run_phased_static_batch
 from repro.graphs import grid_road
@@ -203,4 +204,5 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_serving.json")
     a = ap.parse_args()
+    use_compile_cache()
     run(a.n, a.queries, a.lanes, a.k, a.rate, a.hot_frac, a.seed, a.out)

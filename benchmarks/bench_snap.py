@@ -14,6 +14,7 @@ import json
 import numpy as np
 
 from benchmarks.common import CRITERIA
+from repro.compile_cache import use_compile_cache
 from repro.core import dijkstra_numpy, run_phased
 from repro.graphs import grid_road, webgraph
 
@@ -55,4 +56,5 @@ if __name__ == "__main__":
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    use_compile_cache()
     run(a.full, a.out)

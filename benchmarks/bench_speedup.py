@@ -16,6 +16,7 @@ import json
 import numpy as np
 
 from benchmarks.common import timed
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     default_delta,
     dijkstra_numpy,
@@ -84,4 +85,5 @@ if __name__ == "__main__":
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    use_compile_cache()
     run(a.full, a.out)

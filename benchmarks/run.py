@@ -13,6 +13,7 @@ import argparse
 import os
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.obs.timer import Stopwatch
 
 
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--seeds", type=int, default=3)
     args = ap.parse_args()
+    use_compile_cache()
 
     os.makedirs("results", exist_ok=True)
     sw = Stopwatch().__enter__()

@@ -16,6 +16,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import dijkstra_numpy, to_ell_in
 from repro.core.static_engine import run_phased_static_batch
 from repro.graphs import grid_road
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--requests", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     side = int(np.sqrt(args.n))
     g = grid_road(side, side, seed=0)

@@ -35,6 +35,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.static_engine import run_phased_static
 from repro.graphs import grid_road
 from repro.obs import Observability
@@ -71,6 +72,7 @@ def main():
                          "random fault plan (faults fire, answers stay "
                          "bit-exact)")
     args = ap.parse_args()
+    use_compile_cache()
 
     side = max(2, int(np.sqrt(args.n)))
     g = grid_road(side, side, seed=args.seed)

@@ -1,8 +1,10 @@
 """Continuous-batching SSSP serving over a mesh-sharded graph.
 
 The ROADMAP's "serve continuously across shards" milestone end to end: this
-process forces 8 fake host devices, block-shards one road graph's vertex
-state over a (4, 2) mesh, and serves asynchronous queries through the same
+process block-shards one road graph's vertex state over a ("data", "model")
+mesh of every device JAX finds (on a four-chip host, 2x2; under
+``JAX_PLATFORMS=cpu`` the process forces 8 fake host devices, 4x2), and
+serves asynchronous queries through the same
 ``ContinuousBatcher`` the single-device demo uses — only the engine backend
 changes (``ShardedBackend``, DESIGN.md Sec. 7). Admission, coalescing, the
 distance cache, and the metrics report are identical, and every completed
@@ -21,16 +23,20 @@ from __future__ import annotations
 import os
 
 _FLAG = "--xla_force_host_platform_device_count=8"
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
+_XLA_FLAGS = os.environ.get("XLA_FLAGS", "")
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count" not in _XLA_FLAGS):
+    os.environ["XLA_FLAGS"] = (_XLA_FLAGS + " " + _FLAG).strip()
 
 import argparse
 
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.static_engine import run_phased_static
 from repro.graphs import grid_road
+from repro.launch.mesh import make_host_mesh
 from repro.serving import ContinuousBatcher, DistCache, ShardedBackend
 
 
@@ -46,8 +52,9 @@ def main():
     ap.add_argument("--hot-frac", type=float, default=0.25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(tp=2)
     side = max(2, int(np.sqrt(args.n)))
     g = grid_road(side, side, seed=args.seed)
     backend = ShardedBackend(g, mesh, ("data", "model"), schedule=args.schedule)

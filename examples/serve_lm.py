@@ -9,6 +9,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.models import decode_step, init_params, prefill
 from repro.obs.timer import now
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.encoder_only:

@@ -9,6 +9,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     dijkstra_numpy,
     run_delta_stepping,
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--deg", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
     n = args.n
 
     graphs = {

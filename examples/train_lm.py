@@ -8,6 +8,7 @@ deterministic pipeline, async checkpointing, automatic resume.
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.data.pipeline import DataConfig
 from repro.launch.mesh import make_host_mesh
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config (CPU-friendly)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh(tp=2)

@@ -57,7 +57,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import criteria as C
 from repro.core.graph import Graph, transpose
@@ -71,7 +71,6 @@ from repro.core.static_engine import (
     combine_limbs,
     validate_sources,
 )
-from repro.sharding.compat import shard_map_compat
 
 INF = jnp.inf
 
@@ -232,22 +231,24 @@ def make_distributed_sssp(mesh: Mesh, axes, *, schedule: str = "reduce_scatter",
 
         state0 = (d, status, jnp.int32(0), any_fringe(status))
         d, status, phases, _ = jax.lax.while_loop(cond, body, state0)
-        return d, phases + jnp.zeros((1,), jnp.int32)
+        # the loop condition is a psum, so every device ran the same trips
+        return d, phases
 
-    mapped = shard_map_compat(
+    # replication checking stays off: the bodies return per-shard blocks
+    mapped = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(vspec, vspec, vspec, vspec, espec, espec, espec, P()),
-        out_specs=(vspec, P(axes[0])),
+        out_specs=(vspec, P()),
+        check_vma=False,
     )
 
     @jax.jit
     def run(sg: ShardedGraph, cap):
-        d, phases = mapped(
+        return mapped(
             sg.d_init, sg.status_init, sg.in_min, sg.out_min,
             sg.src_local, sg.dst, sg.w, cap,
         )
-        return d, phases[0]
 
     return run
 
@@ -380,6 +381,30 @@ def shard_graph_batch(g: Graph, num_shards: int, pad_multiple: int = 8,
         in_min=_pad_min_vec(g.in_min_static, n_pad),
         out_min=_pad_min_vec(g.out_min_static, n_pad),
         out_deg=jnp.asarray(out_deg),
+    )
+
+
+def place_sharded_graph(sg: ShardedBatchGraph, mesh: Mesh,
+                        axes) -> ShardedBatchGraph:
+    """Put each shard's edge rows and vertex block on its own device.
+
+    A graph left on the default device would be re-scattered to the mesh on
+    every step call, and would sit whole on the first device meanwhile.
+    """
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+
+    def put(x, spec):
+        return None if x is None else jax.device_put(
+            x, NamedSharding(mesh, spec))
+
+    edge, vert = P(axes, None), P(axes)
+    return dataclasses.replace(
+        sg,
+        src_local=put(sg.src_local, edge), dst=put(sg.dst, edge),
+        w=put(sg.w, edge), tsrc_local=put(sg.tsrc_local, edge),
+        tdst=put(sg.tdst, edge), tw=put(sg.tw, edge),
+        in_min=put(sg.in_min, vert), out_min=put(sg.out_min, vert),
+        out_deg=put(sg.out_deg, vert),
     )
 
 
@@ -646,7 +671,8 @@ def _get_sharded_step(mesh: Mesh, axes, schedule: str,
          trips, trace, _) = jax.lax.while_loop(cond, body, carry)
         return d, status, phases, sum_f, sum_f_hi, redges, redges_hi, trips, trace
 
-    mapped = shard_map_compat(
+    # replication checking stays off: the bodies return per-shard blocks
+    mapped = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(bspec, bspec, rspec, rspec, rspec, rspec, rspec, rspec,
@@ -811,9 +837,11 @@ def harvest_sharded(state: ShardedBatchState) -> BatchedResult:
     phase's count), so it maps to None rather than a fake one-slot profile.
     """
     trace = state.settled_trace if state.settled_trace.shape[1] > 1 else None
+    # drop the padding columns on the host: a global slice to n columns is
+    # not divisible by the mesh axes, which sharding-typed meshes refuse
     return BatchedResult(
-        dist=state.dist[:, : state.n],
-        status=state.status[:, : state.n].astype(jnp.int8),
+        dist=np.asarray(state.dist)[:, : state.n],
+        status=np.asarray(state.status)[:, : state.n].astype(np.int8),
         phases=state.phases,
         sum_fringe=combine_limbs(state.sum_fringe, state.sum_fringe_hi),
         relax_edges=combine_limbs(state.relax_edges, state.relax_edges_hi),

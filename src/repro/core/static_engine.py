@@ -41,8 +41,9 @@ bit-identical; sliced wins on skewed (rmat-style) degree distributions
 where padded rows pay the hub width (DESIGN.md Sec. 9).
 
 This is the single-device building block that ``repro.core.distributed``
-shard_maps over the production mesh. ``use_pallas=False`` swaps in the ref.py
-oracles (bit-identical math) for differential testing, and every
+shard_maps over the production mesh. The phase body runs the ref.py XLA
+forms by default — the program that compiles for TPU; ``use_pallas=True``
+opts into the Pallas kernels (bit-identical math), and every
 engine x criterion combination is bit-exact per row against ``run_phased``
 with the same criterion string (pinned by ``tests/test_stepper_criteria.py``).
 
@@ -583,7 +584,7 @@ def step_batch(
     state: BatchState,
     k_phases: int,
     ell=None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
     stop_on_lane_finish: bool = False,
     donate: bool = False,
     ell_out=None,
@@ -859,7 +860,7 @@ def run_phased_static(
     g: Graph,
     source: int = 0,
     ell=None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
     max_phases: int | None = None,
     criterion: str = DEFAULT_CRITERION,
     dist_true=None,
@@ -869,7 +870,7 @@ def run_phased_static(
     delta: float | None = None,
     target: int | None = None,
 ) -> PhasedResult:
-    """Phased SSSP via the Pallas kernels (B=1 stepper), any policy spec.
+    """Phased SSSP on the B=1 stepper, any policy spec.
 
     ``trace_len`` sizes the settled-per-phase ring; the default (None)
     covers the phase cap so the result carries the *full* per-phase profile
@@ -923,7 +924,7 @@ def run_phased_static_batch(
     g: Graph,
     sources,
     ell=None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
     max_phases: int | None = None,
     criterion: str = DEFAULT_CRITERION,
     dist_true=None,
@@ -943,7 +944,8 @@ def run_phased_static_batch(
         — pass it when answering many batches against the same graph so the
         ELL build is paid once (both builders also memoise per Graph
         instance).
-      use_pallas: kernels (True) vs ref.py oracles (False); bit-identical.
+      use_pallas: Pallas kernels (True) vs the ref.py XLA forms (False, the
+        default); bit-identical.
       max_phases: safety cap on loop trips (default the policy's cap:
         criterion plans settle >= 1 vertex per phase so n+1 suffices;
         delta-stepping uses the legacy 4n+16 light/heavy-round bound).

@@ -108,6 +108,14 @@ def vmem_budget_bytes() -> int:
     return int(raw) if raw else DEFAULT_VMEM_BUDGET
 
 
+def env_overrides() -> dict[str, str]:
+    """Every ``REPRO_*`` variable set in the environment (the execution-mode,
+    scan-shape and VMEM overrides and the tuning-ledger path): a run that
+    must execute the program the source defines refuses to start while any
+    is set."""
+    return {k: v for k, v in os.environ.items() if k.startswith("REPRO_")}
+
+
 def scan_vmem_bytes(n_idx: int, d_pad: int, b: int, block_rows: int,
                     vecs: int = 1, outs: int = 1) -> int:
     """Resident-VMEM estimate of one ELL row-scan grid step.

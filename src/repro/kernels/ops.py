@@ -6,10 +6,12 @@ interpret elsewhere, unless ``REPRO_KERNEL_MODE`` overrides", and
 ``block_rows=None`` / ``block=None`` consult the tuning ledger before
 falling back to a VMEM-budget default. Explicit arguments always win.
 
-Every wrapper also accepts ``use_pallas``: the False path runs the ref.py
-oracle *through the same padding/masking code* as the kernel path, so the
-two can never drift apart bitwise — engines select the path, never pad
-themselves (this is THE one home of the sentinel/alignment convention).
+Every batched wrapper also accepts ``use_pallas``. The default (False) runs
+the ref.py XLA form — the path the engines run, on TPU as on CPU — *through
+the same padding/masking code* as the kernel path, so the two can never
+drift apart bitwise; ``use_pallas=True`` opts into the Pallas kernels.
+Engines select the path, never pad themselves (this is THE one home of the
+sentinel/alignment convention).
 
 Adjacency layouts: wrappers taking an ``ell`` argument accept either the
 padded ``(cols, ws)`` pair (``to_ell_in``) or a degree-sliced
@@ -26,6 +28,8 @@ surfaces — ``tests/test_kernels.py`` pins the 2-D kernels row-for-row
 against them (DESIGN.md Sec. 5), so they must stay bit-consistent.
 """
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -127,7 +131,7 @@ def relax_settled_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """Batched candidate updates (B, n); one adjacency load serves all rows."""
     interpret = kcfg.resolve_interpret(interpret)
@@ -149,7 +153,7 @@ def relax_settled_batch_sliced(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """Sliced-layout twin of :func:`relax_settled_batch` (bit-identical)."""
     dmask = jnp.where(settle_mask, d, INF)
@@ -165,7 +169,7 @@ def gather_min_batch_sliced(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """(V, B, n) per-vector row-mins over a degree-sliced adjacency.
 
@@ -223,7 +227,7 @@ def crit_thresholds_batch(
     *,
     block: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ):
     """Plan-lane thresholds: (mins (1+K, B), |F| (B,)) in one fused pass.
 
@@ -246,7 +250,7 @@ def key_min_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """Dynamic criterion key (B, n): per-lane min of gate[neighbour] + w.
 
@@ -309,7 +313,7 @@ def delta_relax_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """Delta-stepping candidate updates (B, n): the light relaxation of
     ``light_from`` and the heavy relaxation of ``heavy_from``, min-merged.
@@ -358,7 +362,7 @@ def relax_settled_gated_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """Goal-directed twin of :func:`relax_settled_batch` (either layout):
     settled sources at or beyond ``bound`` are masked out of the scan."""
@@ -379,7 +383,7 @@ def in_scan_relax_keys_gated_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ):
     """Goal-directed twin of :func:`in_scan_relax_keys_batch`.
 
@@ -439,7 +443,7 @@ def in_scan_relax_keys_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ):
     """The fused in-scan: ``(upd (B, n), keys (K, B, n))``.
 
@@ -505,7 +509,7 @@ def out_scan_keys_batch(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
 ) -> jax.Array:
     """The fused out-scan: keys ``(K0 [+1], B, n)``.
 
@@ -758,6 +762,10 @@ def register_kernels(reg):
         ("out_scan_keys_batch", out_scan_keys_batch, cases_out_scan,
          {"resident_outputs": (0,)}),
     ):
+        if fn not in (relax_settled, static_thresholds,
+                      static_thresholds_batch):
+            # the contracts audit the kernel path, which callers opt into
+            fn = partial(fn, use_pallas=True)
         reg.register(R.KernelContract(
             name=name, module=__name__, wrapper=fn, make_cases=cases,
             **extra,

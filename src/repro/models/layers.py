@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.sharding.compat import shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -365,7 +364,8 @@ def _apply_moe_ep(cfg: ModelConfig, p, x, shd: ShardingCtx):
         y = _moe_dispatch_compute(cfg, router, xt, c_loc, expert_fn)
         return y.reshape(xb.shape)
 
-    mapped = shard_map_compat(
+    # replication checking stays off: the bodies return per-shard blocks
+    mapped = jax.shard_map(
         spmd,
         mesh=shd.mesh,
         in_specs=(
@@ -376,6 +376,7 @@ def _apply_moe_ep(cfg: ModelConfig, p, x, shd: ShardingCtx):
             P(shd.tp, None, None),
         ),
         out_specs=P(shd.dp, shd.tp, None),
+        check_vma=False,
     )
     return mapped(x, p["router"], p["wi"], p["wg"], p["wo"])
 

@@ -5,8 +5,8 @@
 ``reset_lanes`` / ``peek`` / ``take_row``) over any resumable B-lane phase
 stepper. Two implementations exist:
 
-  * :class:`StaticBackend` — the single-device Pallas stepper
-    (``repro.core.static_engine``): ``(B, n)`` state, ELL pull kernels.
+  * :class:`StaticBackend` — the single-device stepper
+    (``repro.core.static_engine``): ``(B, n)`` state, ELL pull scans.
   * :class:`ShardedBackend` — the mesh stepper
     (``repro.core.distributed``): ``(B, n_pad)`` state block-sharded over
     the mesh's vertex axis, COO push + one vector collective per phase.
@@ -156,7 +156,7 @@ class StaticBackend:
     pre-target engine program.
     """
 
-    def __init__(self, g: Graph, ell=None, use_pallas: bool = True,
+    def __init__(self, g: Graph, ell=None, use_pallas: bool = False,
                  criterion: str = DEFAULT_CRITERION, layout: str = "padded",
                  policy: str | None = None, delta: float | None = None,
                  point_queries: bool = False):
@@ -228,7 +228,7 @@ class ShardedBackend:
                  pad_multiple: int = 8, criterion: str = DEFAULT_CRITERION):
         # imported lazily-ish at construction: the distributed module pulls
         # in shard_map machinery the static serving path never needs
-        from repro.core.distributed import shard_graph_batch
+        from repro.core.distributed import place_sharded_graph, shard_graph_batch
 
         if isinstance(axes, str):
             axes = (axes,)
@@ -242,8 +242,11 @@ class ShardedBackend:
         # the backend's criterion is fixed for its lifetime, so only build
         # the transpose edge partition when the plan's dynamic OUT keys
         # will actually read it (it doubles resident edge memory)
-        self.sg = shard_graph_batch(g, num, pad_multiple=pad_multiple,
-                                    with_transpose=plan.needs_out_adjacency)
+        self.sg = place_sharded_graph(
+            shard_graph_batch(g, num, pad_multiple=pad_multiple,
+                              with_transpose=plan.needs_out_adjacency),
+            mesh, self.axes,
+        )
 
     @property
     def n(self) -> int:
@@ -462,7 +465,7 @@ def measure_portfolio(
     lanes: int = 8,
     candidates: tuple[EngineCandidate, ...] = DEFAULT_CANDIDATES,
     ledger=None,
-    use_pallas: bool = True,
+    use_pallas: bool = False,
     registry=None,
     repeats: int = 2,
 ) -> dict[tuple[str, str], dict]:
@@ -570,7 +573,7 @@ class PortfolioBackend:
 
     def __init__(self, g: Graph, lanes_hint: int = 8,
                  candidates: tuple[EngineCandidate, ...] = DEFAULT_CANDIDATES,
-                 ledger=None, use_pallas: bool = True, probe: bool = False,
+                 ledger=None, use_pallas: bool = False, probe: bool = False,
                  registry=None, point_queries: bool = False):
         from repro.kernels import config as kcfg
 

@@ -128,7 +128,7 @@ class PointBackend:
 
     def __init__(self, g: Graph, *, criterion: str = DEFAULT_CRITERION,
                  policy: str | None = None, layout: str = "padded",
-                 use_pallas: bool = True, bidirectional: bool = True,
+                 use_pallas: bool = False, bidirectional: bool = True,
                  phases_per_chunk: int = 8):
         spec = policy if policy is not None else criterion
         pol = _serving_policy(spec)
@@ -254,7 +254,7 @@ def run_point_to_point(
     criterion: str = DEFAULT_CRITERION,
     policy: str | None = None,
     layout: str = "padded",
-    use_pallas: bool = True,
+    use_pallas: bool = False,
     bidirectional: bool = True,
     phases_per_chunk: int = 8,
 ) -> PointResult:

@@ -107,8 +107,8 @@ class ContinuousBatcher:
         are still live; large k amortises the per-step host sync. k is a
         traced operand, so changing it does not recompile.
       ell: optional precomputed ``to_ell_in(g)`` (static backend only).
-      use_pallas: kernels (True) vs ref oracles (False); bit-identical.
-        (Static backend only.)
+      use_pallas: Pallas kernels (True) vs the ref.py XLA forms (False, the
+        default); bit-identical. (Static backend only.)
       cache: optional :class:`DistCache`; duplicate sources short-circuit
         (completed ones from the cache, in-flight ones by coalescing onto
         the lane already solving that source).
@@ -167,7 +167,7 @@ class ContinuousBatcher:
         lanes: int = 8,
         phases_per_step: int = 32,
         ell=None,
-        use_pallas: bool = True,
+        use_pallas: bool = False,
         cache: DistCache | None = None,
         clock=timer.now,
         retain_completed: int | None = 1024,

@@ -35,9 +35,12 @@ for g in [uniform_gnp(300, 8/300, seed=3), grid_road(12, 14, seed=4)]:
         assert np.allclose(d[fin], ref[fin], rtol=1e-5), sched
         assert int(ph) == int(base.phases), (sched, int(ph), int(base.phases))
 
-# --- sharded training with EP MoE on the mesh: loss finite and falling
+# --- sharded training with EP MoE on the mesh: loss finite and falling (the
+# LM layers need the Auto-typed mesh the launch helpers build)
+from repro.launch.mesh import make_host_mesh
+lm_mesh = make_host_mesh(tp=2)
 cfg = get_smoke("qwen3_moe_235b")
-r = train(cfg, mesh, steps=16, dcfg=DataConfig(seed=0, batch=4, seq_len=64),
+r = train(cfg, lm_mesh, steps=16, dcfg=DataConfig(seed=0, batch=4, seq_len=64),
           opt_cfg=OptConfig(lr=1e-2, warmup_steps=3, total_steps=16))
 assert all(np.isfinite(r.losses)), r.losses
 assert min(r.losses[8:]) < r.losses[0] + 0.02, r.losses

@@ -249,10 +249,12 @@ def test_autotune_slicing_ledger_feeds_the_builders():
         if bset is None:
             cols, ws = to_ell_in(g)
             return lambda: kops.relax_settled_batch(d, settle, cols, ws,
-                                                    interpret=True)
+                                                    interpret=True,
+                                                    use_pallas=True)
         sl = to_ell_in_sliced(g, boundaries=bset)
         return lambda: kops.relax_settled_batch_sliced(d, settle, sl,
-                                                       interpret=True)
+                                                       interpret=True,
+                                                       use_pallas=True)
 
     led = kcfg.TuningLedger()
     win = kcfg.autotune_slicing(make_call, g.n,

@@ -145,7 +145,7 @@ def test_relax_settled_batch_matches_single():
     b = 8
     d = jnp.asarray(rng.uniform(0, 3, (b, g.n)).astype(np.float32))
     settle = jnp.asarray(rng.random((b, g.n)) < 0.4)
-    upd = np.asarray(relax_settled_batch(d, settle, cols, ws))
+    upd = np.asarray(relax_settled_batch(d, settle, cols, ws, use_pallas=True))
     for i in range(b):
         single = np.asarray(relax_settled(d[i], settle[i], cols, ws))
         np.testing.assert_array_equal(upd[i], single)

@@ -405,3 +405,47 @@ def test_ell_conversion_is_memoised_per_graph():
     assert a[0] is b[0] and a[1] is b[1]  # cache hit returns same arrays
     c = to_ell_in(g, pad_multiple=16)  # different layout -> distinct entry
     assert c[0] is not a[0] and c[0].shape[1] % 16 == 0
+
+
+def _entry_point_calls(g):
+    """One call through every serving entry point at its defaults."""
+    from repro.core.static_engine import run_phased_static_batch
+    from repro.kernels.config import TuningLedger
+    from repro.serving import PointBackend, PortfolioBackend
+    from repro.serving.backends import EngineCandidate
+
+    yield lambda: run_phased_static(g, 0)
+    yield lambda: run_phased_static_batch(g, [0, 3])
+    yield lambda: PointBackend(g).query(0, g.n - 1)
+    for kw in ({}, {"layout": "sliced", "criterion": "in|out"},
+               {"policy": "delta"}):
+        def serve(kw=kw):
+            server = ContinuousBatcher(
+                g, lanes=2, backend=StaticBackend(g, point_queries=True, **kw),
+                point_queries=True)
+            server.submit(0)
+            server.submit(1, target=g.n - 1)
+            return server.drain(max_steps=1000)
+        yield serve
+    yield lambda: PortfolioBackend(
+        g, lanes_hint=2, ledger=TuningLedger(), probe=True,
+        candidates=(EngineCandidate("in|out", "sliced"),))
+
+
+def test_serving_entry_points_reach_no_pallas_kernel_by_default():
+    """The served path is the XLA stepper: no entry point traces a Pallas
+    kernel unless the caller passes ``use_pallas=True`` (the control)."""
+    from repro.analysis.kernel_audit import capture_pallas_calls
+
+    g = grid_road(5, 6, seed=1)
+    calls: list = []
+    jax.clear_caches()  # force a fresh trace of every program below
+    try:
+        with capture_pallas_calls(calls):
+            for call in _entry_point_calls(g):
+                call()
+            assert calls == []
+            run_phased_static(g, 0, use_pallas=True, max_phases=1)
+            assert calls, "the recorder saw no kernel on the opt-in path"
+    finally:
+        jax.clear_caches()  # drop programs traced with the fake kernels
