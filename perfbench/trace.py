@@ -1,0 +1,190 @@
+"""Reduce a profiler trace (``.xplane.pb``) to the benchmark's numbers.
+
+- busy: the union of the intervals in which an op ran on a device, over
+  the traced window, averaged over the devices that ran any;
+- idle gaps: the complements of those intervals in the window, each named
+  by the innermost host span (``TraceAnnotation``) covering its midpoint;
+- op top-list: device time by op name, counted as self time (an op's
+  duration less the ops nested in it on the same line).
+
+Device planes are named ``/device:<kind>:<i>``; their ops lie on the line
+``XLA Ops``. Host spans are the events of the host planes whose names
+start with one of ``HOST_PREFIXES``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import glob
+import os
+import re
+from collections import defaultdict
+
+OPS_LINE = "XLA Ops"
+HOST_PREFIXES = ("bench.", "backend.")
+TOP = 10
+
+
+@dataclasses.dataclass
+class TraceSummary:
+    window_s: float
+    busy_s: float  # mean over devices with ops
+    devices: int
+    device_ops: list  # [[name, seconds], ...] by self time, top first
+    idle_gaps: list  # [[host span name, seconds], ...], largest first
+
+
+def merge(intervals):
+    """Sorted, disjoint union of ``(start, end)`` intervals."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            if e > out[-1][1]:
+                out[-1][1] = e
+        else:
+            out.append([s, e])
+    return out
+
+
+def clip(intervals, lo, hi):
+    return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if e > lo and s < hi]
+
+
+def covered(intervals) -> float:
+    return float(sum(e - s for s, e in merge(intervals)))
+
+
+def gaps(busy, lo, hi):
+    """The idle intervals of ``[lo, hi]`` outside the merged ``busy``."""
+    out, t = [], lo
+    for s, e in merge(clip(busy, lo, hi)):
+        if s > t:
+            out.append((t, s))
+        t = max(t, e)
+    if hi > t:
+        out.append((t, hi))
+    return out
+
+
+def self_times(events):
+    """``{name: self time}`` of ``(start, end, name)`` events on one line,
+    nested ones subtracted from the op that contains them."""
+    tot = defaultdict(float)
+    stack: list = []  # (end, name) of the ops open at the current start
+    for s, e, name in sorted(events, key=lambda x: (x[0], -x[1])):
+        while stack and stack[-1][0] <= s:
+            stack.pop()
+        if stack:  # directly nested: its time is not its parent's own
+            tot[stack[-1][1]] -= e - s
+        tot[name] += e - s
+        stack.append((e, name))
+    return dict(tot)
+
+
+def name_gaps(idle, spans):
+    """Sum idle time by the innermost host span covering each gap's
+    midpoint (``"host: outside spans"`` where none does). Spans nest, so
+    one sweep in time order with a stack of open spans finds it."""
+    out = defaultdict(float)
+    spans = sorted(spans, key=lambda x: (x[0], -x[1]))
+    stack: list = []  # (end, name) of the spans open at the sweep point
+    j = 0
+    for s, e in sorted(idle, key=lambda x: x[0] + x[1]):
+        mid = 0.5 * (s + e)
+        while j < len(spans) and spans[j][0] <= mid:
+            while stack and stack[-1][0] < spans[j][0]:
+                stack.pop()
+            stack.append((spans[j][1], spans[j][2]))
+            j += 1
+        while stack and stack[-1][0] < mid:
+            stack.pop()
+        out[stack[-1][1] if stack else "host: outside spans"] += e - s
+    return out
+
+
+def summarize(device_events, host_spans, lo, hi) -> TraceSummary:
+    """Numbers of the window ``[lo, hi]`` (seconds on the trace's clock)
+    from ``{device: [(start, end, op name)]}`` and host spans."""
+    busy_by_dev, idle_by_name, ops = [], defaultdict(float), defaultdict(float)
+    for events in device_events.values():
+        iv = clip([(s, e) for s, e, _ in events], lo, hi)
+        if not iv:
+            continue
+        busy_by_dev.append(covered(iv))
+        for name, t in name_gaps(gaps(iv, lo, hi), host_spans).items():
+            idle_by_name[name] += t
+        inside = [(max(s, lo), min(e, hi), n) for s, e, n in events
+                  if e > lo and s < hi]
+        for name, t in self_times(inside).items():
+            ops[name] += t
+    k = max(len(busy_by_dev), 1)
+    top_ops = sorted(ops.items(), key=lambda x: -x[1])[:TOP]
+    top_gaps = sorted(idle_by_name.items(), key=lambda x: -x[1])[:TOP]
+    return TraceSummary(
+        window_s=hi - lo,
+        busy_s=sum(busy_by_dev) / k,
+        devices=len(busy_by_dev),
+        device_ops=[[n, t / k] for n, t in top_ops],
+        idle_gaps=[[n, t / k] for n, t in top_gaps],
+    )
+
+
+def op_name(hlo: str) -> str:
+    """``%fusion.49 = f32[8,512]{1,0:T(8,128)} fusion(...), ...`` ->
+    ``fusion.49 = f32[8,512] fusion``: the op, its output and its kind."""
+    lhs, _, rhs = hlo.partition(" = ")
+    lhs = lhs.lstrip("%")
+    m = re.match(r"\s*(\(.*?\)|\S+)\s+([\w\-]+)\(",
+                 re.sub(r"\{[^}]*\}", "", rhs))
+    if not m:
+        return lhs
+    shape = "(...)" if m.group(1).startswith("(") else m.group(1)
+    return f"{lhs} = {shape} {m.group(2)}"
+
+
+def read_xplane(path):
+    """``({device plane: [(start, end, op)]}, [(start, end, host span)])``
+    in seconds, from one ``.xplane.pb`` file."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(path)
+    devices, spans, names = {}, [], {}
+    for plane in pd.planes:
+        if plane.name.startswith("/device:"):
+            evs = []
+            for line in plane.lines:
+                if line.name != OPS_LINE:
+                    continue
+                for e in line.events:
+                    name = names.get(e.name)
+                    if name is None:
+                        name = names[e.name] = op_name(e.name)
+                    evs.append((e.start_ns * 1e-9,
+                                (e.start_ns + e.duration_ns) * 1e-9, name))
+            if evs:
+                devices[plane.name] = evs
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans += [(e.start_ns * 1e-9,
+                           (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                          for e in line.events
+                          if e.name.startswith(HOST_PREFIXES)]
+    return devices, spans
+
+
+def find_xplane(log_dir) -> str:
+    found = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    return found[-1]
+
+
+def summarize_file(path, window_span: str = "bench.window") -> TraceSummary:
+    """Summary of the window that the host span ``window_span`` marks."""
+    devices, spans = read_xplane(path)
+    win = [(s, e) for s, e, n in spans if n == window_span]
+    if not win:
+        raise ValueError(f"no host span {window_span!r} in {path}")
+    lo, hi = win[-1]
+    return summarize(devices, spans, lo, hi)
