@@ -283,116 +283,121 @@ class CriterionPolicy(PhasePolicy):
         in_slots = _in_slot_indices(plan)
         d, status = s.dist, s.status
         fringe = status == 1
-        # --- out-scan: every out-side dynamic key from one fused launch
-        keys = _compute_out_keys(plan, g, status, ell_out, use_pallas)
-        # in-side keys ride in from the previous phase's in-scan (or the
-        # pre-loop priming); by invariant they match the current status
-        for i, nm in zip(in_slots, plan.in_scan_keys):
-            keys[nm] = s.crit_keys[i]
-        mins, n_f = kops.crit_thresholds_batch(
-            d, status, _threshold_keys(plan, g, keys, b),
-            use_pallas=use_pallas,
-        )
-        term_masks = None
-        if s.attr_trace is not None:
-            # telemetry path: materialise each member's settle mask so the
-            # attribution ring can credit every settled vertex to the first
-            # member that proved it; the union is boolean-identical to
-            # plan_union_mask (same masks, OR'd)
-            term_masks = C.plan_term_masks(
-                plan, d, fringe, mins, keys, g.in_min_static, s.dist_true
+        with jax.named_scope("sssp.out_scan"):
+            # --- out-scan: every out-side dynamic key from one fused launch
+            keys = _compute_out_keys(plan, g, status, ell_out, use_pallas)
+        with jax.named_scope("sssp.thresholds"):
+            # in-side keys ride in from the previous phase's in-scan (or the
+            # pre-loop priming); by invariant they match the current status
+            for i, nm in zip(in_slots, plan.in_scan_keys):
+                keys[nm] = s.crit_keys[i]
+            mins, n_f = kops.crit_thresholds_batch(
+                d, status, _threshold_keys(plan, g, keys, b),
+                use_pallas=use_pallas,
             )
-            settle = term_masks[0]
-            for m in term_masks[1:]:
-                settle = settle | m
-        else:
-            settle = C.plan_union_mask(
-                plan, d, fringe, mins, keys, g.in_min_static, s.dist_true
-            )
-        if plan.needs_fallback:
-            # bare-oracle plans can produce an empty mask on a non-empty
-            # fringe (f32-vs-f64 tolerance); reproduce evaluate()'s DIJK
-            # guard per lane so progress — and run_phased parity — hold
-            dijk = fringe & (d <= mins[0][:, None])
-            settle = jnp.where(
-                jnp.any(settle, axis=1, keepdims=True), settle, dijk
-            )
-        # --- goal-directed pruning bound (target lanes only): the target's
-        # current tentative distance. Settled sources at or beyond it can
-        # never improve tent(target) (non-negative f32 adds are monotone
-        # and the bound never drops below the target's final distance), so
-        # the gated relax variants drop them from the scans — the settle
-        # DECISION above is untouched, only the relax work shrinks. The
-        # branch is structural: target-free states trace the exact
-        # pre-target program.
-        bound = None
-        if s.target is not None:
-            b_rows = jnp.arange(b)
-            tcol = jnp.clip(s.target, 0, d.shape[1] - 1)
-            bound = jnp.where(s.target >= 0, d[b_rows, tcol], INF)
-            relax_from = settle & (d < bound[:, None])
-        else:
-            relax_from = settle
-        # --- in-scan: relax this phase; fused plans also emit the NEXT
-        # phase's in-side keys from the same tile loads
-        next_in = None
-        if in_slots:
-            # key gates come from the FULL settle mask (they encode the
-            # post-settle status, which pruning does not change)
-            parts = [
-                C.in_scan_gate_parts(_spec_by_name(plan, nm), status, settle,
-                                     g.in_min_static[None])
-                for nm in plan.in_scan_keys
-            ]
-            if bound is not None:
-                upd, next_in = kops.in_scan_relax_keys_gated_batch(
-                    d, settle, bound, parts, ell_in, use_pallas=use_pallas
+        with jax.named_scope("sssp.settle"):
+            term_masks = None
+            if s.attr_trace is not None:
+                # telemetry path: materialise each member's settle mask so the
+                # attribution ring can credit every settled vertex to the first
+                # member that proved it; the union is boolean-identical to
+                # plan_union_mask (same masks, OR'd)
+                term_masks = C.plan_term_masks(
+                    plan, d, fringe, mins, keys, g.in_min_static, s.dist_true
+                )
+                settle = term_masks[0]
+                for m in term_masks[1:]:
+                    settle = settle | m
+            else:
+                settle = C.plan_union_mask(
+                    plan, d, fringe, mins, keys, g.in_min_static, s.dist_true
+                )
+            if plan.needs_fallback:
+                # bare-oracle plans can produce an empty mask on a non-empty
+                # fringe (f32-vs-f64 tolerance); reproduce evaluate()'s DIJK
+                # guard per lane so progress — and run_phased parity — hold
+                dijk = fringe & (d <= mins[0][:, None])
+                settle = jnp.where(
+                    jnp.any(settle, axis=1, keepdims=True), settle, dijk
+                )
+        with jax.named_scope("sssp.relax"):
+            # --- goal-directed pruning bound (target lanes only): the target's
+            # current tentative distance. Settled sources at or beyond it can
+            # never improve tent(target) (non-negative f32 adds are monotone
+            # and the bound never drops below the target's final distance), so
+            # the gated relax variants drop them from the scans — the settle
+            # DECISION above is untouched, only the relax work shrinks. The
+            # branch is structural: target-free states trace the exact
+            # pre-target program.
+            bound = None
+            if s.target is not None:
+                b_rows = jnp.arange(b)
+                tcol = jnp.clip(s.target, 0, d.shape[1] - 1)
+                bound = jnp.where(s.target >= 0, d[b_rows, tcol], INF)
+                relax_from = settle & (d < bound[:, None])
+            else:
+                relax_from = settle
+            # --- in-scan: relax this phase; fused plans also emit the NEXT
+            # phase's in-side keys from the same tile loads
+            next_in = None
+            if in_slots:
+                # key gates come from the FULL settle mask (they encode the
+                # post-settle status, which pruning does not change)
+                parts = [
+                    C.in_scan_gate_parts(_spec_by_name(plan, nm), status, settle,
+                                         g.in_min_static[None])
+                    for nm in plan.in_scan_keys
+                ]
+                if bound is not None:
+                    upd, next_in = kops.in_scan_relax_keys_gated_batch(
+                        d, settle, bound, parts, ell_in, use_pallas=use_pallas
+                    )
+                else:
+                    upd, next_in = kops.in_scan_relax_keys_batch(
+                        d, settle, parts, ell_in, use_pallas=use_pallas
+                    )
+            elif bound is not None:
+                upd = kops.relax_settled_gated_batch(
+                    d, settle, bound, ell_in, use_pallas=use_pallas
+                )
+            elif kops._is_sliced(ell_in):
+                upd = kops.relax_settled_batch_sliced(
+                    d, settle, ell_in, use_pallas=use_pallas
                 )
             else:
-                upd, next_in = kops.in_scan_relax_keys_batch(
-                    d, settle, parts, ell_in, use_pallas=use_pallas
+                upd = kops.relax_settled_batch(
+                    d, settle, ell_in[0], ell_in[1], use_pallas=use_pallas
                 )
-        elif bound is not None:
-            upd = kops.relax_settled_gated_batch(
-                d, settle, bound, ell_in, use_pallas=use_pallas
+        with jax.named_scope("sssp.update"):
+            new_d = jnp.minimum(d, upd)
+            new_status = jnp.where(
+                settle, 2, jnp.where((status == 0) & (upd < INF), 1, status)
             )
-        elif kops._is_sliced(ell_in):
-            upd = kops.relax_settled_batch_sliced(
-                d, settle, ell_in, use_pallas=use_pallas
+            n_settled = jnp.sum(settle, axis=1, dtype=jnp.int32)
+            relax_inc = jnp.sum(
+                jnp.where(relax_from, s.out_deg[None], 0).astype(jnp.uint32),
+                axis=1, dtype=jnp.uint32,
             )
-        else:
-            upd = kops.relax_settled_batch(
-                d, settle, ell_in[0], ell_in[1], use_pallas=use_pallas
-            )
-        new_d = jnp.minimum(d, upd)
-        new_status = jnp.where(
-            settle, 2, jnp.where((status == 0) & (upd < INF), 1, status)
-        )
-        n_settled = jnp.sum(settle, axis=1, dtype=jnp.int32)
-        relax_inc = jnp.sum(
-            jnp.where(relax_from, s.out_deg[None], 0).astype(jnp.uint32),
-            axis=1, dtype=jnp.uint32,
-        )
-        attr_counts = None
-        if s.attr_trace is not None:
-            # first-true claiming partitions the settled set over the plan's
-            # members in canonical order; a vertex proven by several members
-            # counts once, so per-term counts sum exactly to n_settled
-            claimed = jnp.zeros_like(settle)
-            counts = []
-            for m in term_masks:
-                take = m & settle & ~claimed
-                counts.append(jnp.sum(take, axis=1, dtype=jnp.int32))
-                claimed = claimed | take
-            if plan.needs_fallback:
-                # residual slot: vertices the DIJK progress guard settled
-                counts.append(n_settled - sum(counts))
-            attr_counts = jnp.stack(counts, axis=1)  # (B, T)
-        crit_keys = s.crit_keys
-        if plan.keys:
-            crit_keys = jnp.stack([keys[k.name] for k in plan.keys])
-            for j, i in enumerate(in_slots):
-                crit_keys = crit_keys.at[i].set(next_in[j])
+            attr_counts = None
+            if s.attr_trace is not None:
+                # first-true claiming partitions the settled set over the plan's
+                # members in canonical order; a vertex proven by several members
+                # counts once, so per-term counts sum exactly to n_settled
+                claimed = jnp.zeros_like(settle)
+                counts = []
+                for m in term_masks:
+                    take = m & settle & ~claimed
+                    counts.append(jnp.sum(take, axis=1, dtype=jnp.int32))
+                    claimed = claimed | take
+                if plan.needs_fallback:
+                    # residual slot: vertices the DIJK progress guard settled
+                    counts.append(n_settled - sum(counts))
+                attr_counts = jnp.stack(counts, axis=1)  # (B, T)
+            crit_keys = s.crit_keys
+            if plan.keys:
+                crit_keys = jnp.stack([keys[k.name] for k in plan.keys])
+                for j, i in enumerate(in_slots):
+                    crit_keys = crit_keys.at[i].set(next_in[j])
         return PhaseOutcome(
             dist=new_d, status=new_status, crit_keys=crit_keys,
             n_fringe=n_f, n_settled=n_settled, relax_inc=relax_inc,
@@ -477,48 +482,52 @@ class DeltaPolicy(PhasePolicy):
         fringe = status == 1
         last_proc = s.crit_keys[0]
         removed = s.crit_keys[1] > 0.5
-        # bucket id per fringe vertex; the fused threshold kernel reduces it
-        # to the lane's current bucket and counts the fringe in one pass
-        bidx = jnp.where(fringe, jnp.floor(d / s.delta), INF)
-        mins, n_f = kops.crit_thresholds_batch(
-            bidx, status, None, use_pallas=use_pallas
-        )
-        b_lane = mins[0]  # (B,) current bucket id (+inf on empty lanes)
-        in_bucket = fringe & (bidx == b_lane[:, None])
-        cur = in_bucket & (d < last_proc)  # light-round work set
-        light_round = jnp.any(cur, axis=1)  # (B,)
-        heavy_turn = ~light_round  # drain done (or lane idle)
-        heavy_from = heavy_turn[:, None] & removed
-        # one double-gated adjacency scan: light edges from this round's
-        # work set, heavy edges from the removed set on the heavy turn
-        upd = kops.delta_relax_batch(
-            d, cur, heavy_from, ell_light, ell_heavy, use_pallas=use_pallas
-        )
-        settle = heavy_from  # the bucket settles on its heavy turn
-        new_d = jnp.minimum(d, upd)
-        new_status = jnp.where(
-            settle, 2, jnp.where((status == 0) & (upd < INF), 1, status)
-        )
-        # drain bookkeeping: light rounds record the processed tentatives
-        # and extend `removed`; the heavy turn resets both for the next
-        # bucket (an idle lane is a fixed point: both already fresh)
-        new_last = jnp.where(
-            heavy_turn[:, None], INF, jnp.where(cur, d, last_proc)
-        )
-        new_removed = jnp.where(heavy_turn[:, None], False, removed | cur)
-        crit_keys = jnp.stack([new_last, new_removed.astype(jnp.float32)])
-        n_settled = jnp.sum(settle, axis=1, dtype=jnp.int32)
-        relax_inc = (
-            jnp.sum(jnp.where(cur, deg_light[None], 0), axis=1,
-                    dtype=jnp.int32)
-            + jnp.sum(jnp.where(heavy_from, deg_heavy[None], 0), axis=1,
-                      dtype=jnp.int32)
-        ).astype(jnp.uint32)
-        attr_counts = None
-        if s.attr_trace is not None:
-            n_light = jnp.sum(cur, axis=1, dtype=jnp.int32)
-            bucket_id = jnp.where(n_f > 0, b_lane, 0.0).astype(jnp.int32)
-            attr_counts = jnp.stack([n_light, n_settled, bucket_id], axis=1)
+        with jax.named_scope("sssp.thresholds"):
+            # bucket id per fringe vertex; the fused threshold kernel reduces it
+            # to the lane's current bucket and counts the fringe in one pass
+            bidx = jnp.where(fringe, jnp.floor(d / s.delta), INF)
+            mins, n_f = kops.crit_thresholds_batch(
+                bidx, status, None, use_pallas=use_pallas
+            )
+        with jax.named_scope("sssp.settle"):
+            b_lane = mins[0]  # (B,) current bucket id (+inf on empty lanes)
+            in_bucket = fringe & (bidx == b_lane[:, None])
+            cur = in_bucket & (d < last_proc)  # light-round work set
+            light_round = jnp.any(cur, axis=1)  # (B,)
+            heavy_turn = ~light_round  # drain done (or lane idle)
+            heavy_from = heavy_turn[:, None] & removed
+            settle = heavy_from  # the bucket settles on its heavy turn
+        with jax.named_scope("sssp.relax"):
+            # one double-gated adjacency scan: light edges from this round's
+            # work set, heavy edges from the removed set on the heavy turn
+            upd = kops.delta_relax_batch(
+                d, cur, heavy_from, ell_light, ell_heavy, use_pallas=use_pallas
+            )
+        with jax.named_scope("sssp.update"):
+            new_d = jnp.minimum(d, upd)
+            new_status = jnp.where(
+                settle, 2, jnp.where((status == 0) & (upd < INF), 1, status)
+            )
+            # drain bookkeeping: light rounds record the processed tentatives
+            # and extend `removed`; the heavy turn resets both for the next
+            # bucket (an idle lane is a fixed point: both already fresh)
+            new_last = jnp.where(
+                heavy_turn[:, None], INF, jnp.where(cur, d, last_proc)
+            )
+            new_removed = jnp.where(heavy_turn[:, None], False, removed | cur)
+            crit_keys = jnp.stack([new_last, new_removed.astype(jnp.float32)])
+            n_settled = jnp.sum(settle, axis=1, dtype=jnp.int32)
+            relax_inc = (
+                jnp.sum(jnp.where(cur, deg_light[None], 0), axis=1,
+                        dtype=jnp.int32)
+                + jnp.sum(jnp.where(heavy_from, deg_heavy[None], 0), axis=1,
+                          dtype=jnp.int32)
+            ).astype(jnp.uint32)
+            attr_counts = None
+            if s.attr_trace is not None:
+                n_light = jnp.sum(cur, axis=1, dtype=jnp.int32)
+                bucket_id = jnp.where(n_f > 0, b_lane, 0.0).astype(jnp.int32)
+                attr_counts = jnp.stack([n_light, n_settled, bucket_id], axis=1)
         return PhaseOutcome(
             dist=new_d, status=new_status, crit_keys=crit_keys,
             n_fringe=n_f, n_settled=n_settled, relax_inc=relax_inc,

@@ -485,8 +485,9 @@ def _step_batch_impl(
 
     # once-per-chunk invariant repair (e.g. re-priming carried in-side keys
     # after admission) + loop-invariant operands the body closes over
-    state = policy.prime(g, ell_in, state, use_pallas)
-    aux = policy.prepare(g, ell_in, ell_out, state, use_pallas)
+    with jax.named_scope("sssp.prime"):
+        state = policy.prime(g, ell_in, state, use_pallas)
+        aux = policy.prepare(g, ell_in, ell_out, state, use_pallas)
 
     def cond(s):
         live = jnp.any(s.status == 1, axis=1)  # lanes never revive, live <= live0
@@ -500,72 +501,73 @@ def _step_batch_impl(
     def body(s):
         out = policy.phase(g, aux, s, use_pallas)
         n_f, n_settled, relax_inc = out.n_fringe, out.n_settled, out.relax_inc
-        new_status = out.status
-        if s.target is not None:
-            # target-aware early exit: the phase a lane's target settles,
-            # its answer dist[target] is final under the active criterion
-            # (a settled vertex never updates again), so the remaining
-            # fringe is demoted and the lane becomes a fixed point. Every
-            # done-lane consumer — the cond below, stop_on_lane_finish,
-            # lanes_active, the serving peek — already reads "no fringe",
-            # so the exit rides the existing chunking unchanged. The phase
-            # itself still counts (the lane was live through it).
-            tcol = jnp.clip(s.target, 0, s.dist.shape[1] - 1)
-            hit = (s.target >= 0) & (new_status[rows_b, tcol] == 2)
-            new_status = jnp.where(
-                hit[:, None] & (new_status == 1), 0, new_status
+        with jax.named_scope("sssp.update"):
+            new_status = out.status
+            if s.target is not None:
+                # target-aware early exit: the phase a lane's target settles,
+                # its answer dist[target] is final under the active criterion
+                # (a settled vertex never updates again), so the remaining
+                # fringe is demoted and the lane becomes a fixed point. Every
+                # done-lane consumer — the cond below, stop_on_lane_finish,
+                # lanes_active, the serving peek — already reads "no fringe",
+                # so the exit rides the existing chunking unchanged. The phase
+                # itself still counts (the lane was live through it).
+                tcol = jnp.clip(s.target, 0, s.dist.shape[1] - 1)
+                hit = (s.target >= 0) & (new_status[rows_b, tcol] == 2)
+                new_status = jnp.where(
+                    hit[:, None] & (new_status == 1), 0, new_status
+                )
+            live = (n_f > 0).astype(jnp.int32)  # finished/empty lanes stop counting
+            # ring write: phase p lands in slot p % trace_len; dead lanes must
+            # not write (their stuck slot may hold a wrapped live entry)
+            idx = s.phases % trace_len
+            lane_on = n_f > 0
+            trace = s.settled_trace.at[rows_b, idx].set(
+                jnp.where(lane_on, n_settled, s.settled_trace[rows_b, idx])
             )
-        live = (n_f > 0).astype(jnp.int32)  # finished/empty lanes stop counting
-        # ring write: phase p lands in slot p % trace_len; dead lanes must
-        # not write (their stuck slot may hold a wrapped live entry)
-        idx = s.phases % trace_len
-        lane_on = n_f > 0
-        trace = s.settled_trace.at[rows_b, idx].set(
-            jnp.where(lane_on, n_settled, s.settled_trace[rows_b, idx])
-        )
-        fringe_trace, relax_trace, attr_trace = (
-            s.fringe_trace, s.relax_trace, s.attr_trace
-        )
-        if attr_trace is not None:
-            fringe_trace = fringe_trace.at[rows_b, idx].set(
-                jnp.where(lane_on, n_f, fringe_trace[rows_b, idx])
+            fringe_trace, relax_trace, attr_trace = (
+                s.fringe_trace, s.relax_trace, s.attr_trace
             )
-            relax_trace = relax_trace.at[rows_b, idx].set(
-                jnp.where(lane_on, relax_inc.astype(jnp.int32),
-                          relax_trace[rows_b, idx])
+            if attr_trace is not None:
+                fringe_trace = fringe_trace.at[rows_b, idx].set(
+                    jnp.where(lane_on, n_f, fringe_trace[rows_b, idx])
+                )
+                relax_trace = relax_trace.at[rows_b, idx].set(
+                    jnp.where(lane_on, relax_inc.astype(jnp.int32),
+                              relax_trace[rows_b, idx])
+                )
+                attr_trace = attr_trace.at[rows_b, idx].set(
+                    jnp.where(lane_on[:, None], out.attr_counts,
+                              attr_trace[rows_b, idx])
+                )
+            # cumulative work counters are two-limb (u32 lo + i32 hi): summing
+            # the per-phase increments in uint32 keeps even a >2^31-edge phase
+            # exact, and the carry extends past 2^32
+            sf_lo, sf_hi = _limb_add(
+                s.sum_fringe, s.sum_fringe_hi, n_f.astype(jnp.uint32)
             )
-            attr_trace = attr_trace.at[rows_b, idx].set(
-                jnp.where(lane_on[:, None], out.attr_counts,
-                          attr_trace[rows_b, idx])
+            re_lo, re_hi = _limb_add(s.relax_edges, s.relax_edges_hi, relax_inc)
+            return BatchState(
+                dist=out.dist,
+                status=new_status,
+                trips=s.trips + 1,
+                phases=s.phases + live,
+                sum_fringe=sf_lo,
+                sum_fringe_hi=sf_hi,
+                relax_edges=re_lo,
+                relax_edges_hi=re_hi,
+                out_deg=s.out_deg,
+                crit_keys=out.crit_keys,
+                keys_valid=s.keys_valid,
+                dist_true=s.dist_true,
+                settled_trace=trace,
+                fringe_trace=fringe_trace,
+                relax_trace=relax_trace,
+                attr_trace=attr_trace,
+                delta=s.delta,
+                target=s.target,
+                criterion=s.criterion,
             )
-        # cumulative work counters are two-limb (u32 lo + i32 hi): summing
-        # the per-phase increments in uint32 keeps even a >2^31-edge phase
-        # exact, and the carry extends past 2^32
-        sf_lo, sf_hi = _limb_add(
-            s.sum_fringe, s.sum_fringe_hi, n_f.astype(jnp.uint32)
-        )
-        re_lo, re_hi = _limb_add(s.relax_edges, s.relax_edges_hi, relax_inc)
-        return BatchState(
-            dist=out.dist,
-            status=new_status,
-            trips=s.trips + 1,
-            phases=s.phases + live,
-            sum_fringe=sf_lo,
-            sum_fringe_hi=sf_hi,
-            relax_edges=re_lo,
-            relax_edges_hi=re_hi,
-            out_deg=s.out_deg,
-            crit_keys=out.crit_keys,
-            keys_valid=s.keys_valid,
-            dist_true=s.dist_true,
-            settled_trace=trace,
-            fringe_trace=fringe_trace,
-            relax_trace=relax_trace,
-            attr_trace=attr_trace,
-            delta=s.delta,
-            target=s.target,
-            criterion=s.criterion,
-        )
 
     return jax.lax.while_loop(cond, body, state)
 

@@ -1,30 +1,41 @@
-"""Span tracer emitting Chrome trace-event JSON (Perfetto-viewable).
+"""Span tracer: profiler annotations plus Chrome trace-event JSON.
 
-:class:`Tracer` collects trace events in memory and exports the Chrome
-``traceEvents`` JSON format, so any captured run opens directly in
+One span API, two sinks. :meth:`Tracer.span` always enters a
+``jax.profiler.TraceAnnotation`` of the span's name, whether the tracer is
+enabled or not. The profiler keeps it only while a trace is being captured,
+and then it lands on the host plane of the ``.xplane.pb`` file, on the clock
+of the device's ops, so host work and device gaps line up. An *enabled*
+tracer also collects the span as a Chrome ``X`` event in memory and exports
+the ``traceEvents`` JSON format, so a captured run opens directly in
 Perfetto / ``chrome://tracing``: serving lanes render as one timeline row
-each (thread = lane), phase chunks and kernel launches as nested slices,
-queue depth as a counter track.
+each (thread = lane), scheduling rounds and kernel launches as nested
+slices, queue depth as a counter track.
 
-Event taxonomy (DESIGN.md Sec. 11): ``phase``/``chunk`` spans from the
-engine drive loops, ``step`` spans from the serving scheduler, ``launch``
-spans from the kernel autotuner, ``request`` spans covering each query's
+Event taxonomy (DESIGN.md Sec. 11): ``serving.*`` spans of each scheduling
+round (``serving.round`` over ``serving.admit``, ``serving.dispatch``,
+``serving.sync`` and ``serving.harvest``/``serving.take_row``),
+``phase``/``chunk`` spans from the engine drive loops, ``launch`` spans
+from the kernel autotuner, ``request`` spans covering each query's
 arrival-to-completion life, plus ``C`` counter samples (queue depth, busy
-lanes) and ``i`` instants (retrace events, admissions).
+lanes) and ``i`` instants (retrace events, admissions). Instants, counters
+and the ``request`` B/E spans are Chrome-only.
 
 Cost model: a *disabled* tracer must be safe to leave plumbed through hot
-loops — every recording method early-returns on one attribute check, and
-``span()`` returns a shared no-op context manager (no allocation). This is
-the near-zero-when-off contract ``benchmarks/bench_obs.py`` measures.
+loops. Every Chrome recording method early-returns on one attribute check
+and keeps nothing in memory; ``span()`` then costs one profiler annotation,
+which records nothing while no profiler trace is active.
 
-Timestamps come from an injectable clock (seconds; default the obs timer)
-and are exported as microseconds relative to the tracer's construction —
-the same simulated clock the serving benchmarks inject therefore produces
-coherent traces.
+Chrome timestamps come from an injectable clock (seconds; default the obs
+timer) and are exported as microseconds relative to the tracer's
+construction, so the simulated clock the serving benchmarks inject
+produces coherent traces. The profiler annotations carry the profiler's
+own clock.
 """
 from __future__ import annotations
 
 import json
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import timer
 
@@ -36,25 +47,11 @@ _KNOWN_PH = frozenset(_EMITTED_PH) | {"I"}  # legacy spelling of instant
 DEFAULT_PID = "repro"
 
 
-class _NullSpan:
-    """Shared no-op context manager for disabled tracers."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """Open span handle: records one complete ('X') event on exit."""
+    """Open span handle: a profiler annotation, and one complete ('X')
+    Chrome event recorded on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_t0", "_note")
 
     def __init__(self, tracer, name, cat, tid, args):
         self._tracer = tracer
@@ -62,13 +59,16 @@ class _Span:
         self.cat = cat
         self.tid = tid
         self.args = args
+        self._note = TraceAnnotation(name)
 
     def __enter__(self):
+        self._note.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now_us()
+        self._note.__exit__(*exc)
         self._tracer._emit({
             "ph": "X", "name": self.name, "cat": self.cat,
             "pid": self._tracer.pid, "tid": self.tid,
@@ -119,9 +119,10 @@ class Tracer:
 
     def span(self, name: str, cat: str = "default", tid: str | int = "main",
              **args):
-        """Context manager recording one complete ('X') event for the block."""
+        """Context manager over the block: a profiler annotation named
+        ``name`` always, and one complete ('X') Chrome event when enabled."""
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(name)
         return _Span(self, name, cat, tid, args)
 
     def begin(self, name: str, cat: str = "default", tid: str | int = "main",

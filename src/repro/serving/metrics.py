@@ -125,6 +125,11 @@ class ServingMetrics:
             self._c_engine_fail = registry.counter(
                 "serving.engine_failures", "engine step exceptions recovered"
             )
+            self._h_round_host = registry.histogram(
+                "serving.round_host_s",
+                "scheduling round wall time less its waits on the device "
+                "(serving.sync, serving.take_row)"
+            )
 
     def record_completion(self, req: Request) -> None:
         self.completed += 1
@@ -177,6 +182,13 @@ class ServingMetrics:
         if self._registry is not None:
             self._c_trips.inc(int(trips_advanced))
             self._g_busy.set(int(busy_lanes))
+
+    def record_round(self, host_s: float) -> None:
+        """One scheduling round's host time: its wall time less the time
+        the host waited on the device in it. Registry-only: its exact
+        lifetime ``max`` is the signal of a host stall."""
+        if self._registry is not None:
+            self._h_round_host.observe(host_s)
 
     def record_failure(self, req: Request, outcome: str) -> None:
         """One request retired without an answer. Deliberately touches none

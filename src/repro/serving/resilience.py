@@ -178,8 +178,8 @@ class ResilientBatcher(ContinuousBatcher):
         self._release_parked()
         return super()._admit()
 
-    def step(self):
-        done = super().step()
+    def _round(self):
+        done = super()._round()
         if self._terminal:
             # budget-exhausted requests retired by the quarantine/recovery
             # hooks this round: they are part of the round's resolutions
@@ -269,9 +269,9 @@ class ResilientBatcher(ContinuousBatcher):
 
     # -- engine-failure recovery --------------------------------------------
 
-    def _advance_and_peek(self):
+    def _advance_and_peek(self, busy):
         try:
-            return super()._advance_and_peek()
+            return super()._advance_and_peek(busy)
         except Exception as err:  # noqa: BLE001 — recovery seam: anything
             # the engine throws mid-step is handled by a full rebuild, and
             # persistent failure surfaces as outcome="failed" requests

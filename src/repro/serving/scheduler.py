@@ -146,12 +146,16 @@ class ContinuousBatcher:
         runs the exact pre-target engine program.
       obs: optional :class:`repro.obs.Observability` bundle. When given,
         serving metrics additionally stream into its registry
-        (``serving.*`` counters/gauges/histograms) and its tracer records
-        the serving timeline: one thread row per lane carrying each
-        query's occupancy span (B/E), per-round ``step`` spans, admission
-        instants, and queue-depth/busy-lane counter tracks — export with
-        ``obs.tracer.export(path)`` and open in Perfetto. Default None:
-        no tracer, no registry traffic, byte-identical scheduling.
+        (``serving.*`` counters/gauges/histograms, among them
+        ``serving.round_host_s``: each round's wall time less the time the
+        host waited on the device in it) and its tracer records the
+        serving timeline: one thread row per lane carrying each query's
+        occupancy span (B/E), the ``serving.*`` spans of each round,
+        admission instants, and queue-depth/busy-lane counter tracks —
+        export with ``obs.tracer.export(path)`` and open in Perfetto.
+        Default None: no Chrome events, no registry traffic, the same
+        scheduling. The ``serving.*`` spans are profiler annotations
+        either way (see :meth:`step`).
       max_pending: bound on the pending backlog (queued + ready). ``None``
         (default) keeps the unbounded pre-hardening behaviour.
       cache_max_age: TTL for served cache rows, in clock units. ``None``
@@ -219,6 +223,10 @@ class ContinuousBatcher:
         self.queue = ArrivalQueue()
         self.obs = obs
         self._tracer = NULL_TRACER if obs is None else obs.tracer
+        # host time per round needs a registry to land in; without one the
+        # round reads no clock for it
+        self._time_rounds = obs is not None
+        self._round_wait_s = 0.0  # this round's host wait on the device
         self.metrics = ServingMetrics(
             lanes, registry=None if obs is None else obs.registry
         )
@@ -604,15 +612,30 @@ class ContinuousBatcher:
             self._ready.clear()
         return resolved
 
-    def _advance_and_peek(self):
+    def _advance_and_peek(self, busy: int):
         """One engine chunk + host sync. The resilient subclass wraps this
         in recovery; returning ``None`` tells ``step()`` the round was
         aborted (state rebuilt, in-flight work re-queued)."""
-        self.state = self.backend.step(
-            self.state, self.phases_per_step, stop_on_lane_finish=True,
-            donate=self._donate,
-        )
-        return self.backend.peek(self.state)  # host sync
+        with self._tracer.span("serving.dispatch", cat="step",
+                               tid="scheduler", busy=busy):
+            self.state = self.backend.step(
+                self.state, self.phases_per_step, stop_on_lane_finish=True,
+                donate=self._donate,
+            )
+        with self._tracer.span("serving.sync", cat="step", tid="scheduler"):
+            return self._wait(self.backend.peek, self.state)  # host sync
+
+    def _wait(self, fn, *args):
+        """``fn(*args)``, a call in which the host waits on the device. With
+        a registry attached its wall time is kept off the round's host
+        time (``serving.round_host_s``)."""
+        if not self._time_rounds:
+            return fn(*args)
+        t0 = timer.now()
+        try:
+            return fn(*args)
+        finally:
+            self._round_wait_s += timer.now() - t0
 
     def _accept_row(self, req: Request, lane: int, row: np.ndarray,
                     now: float) -> bool:
@@ -628,10 +651,28 @@ class ContinuousBatcher:
         Returns the requests *retired* during this round — completions
         (cache hits and finished lanes, each carrying its ``dist`` row)
         plus any shed on expiry (``outcome != "ok"``, no row).
+
+        The round is spanned (:meth:`repro.obs.Tracer.span`, so the
+        profiler sees the spans whether or not ``obs`` is given):
+        ``serving.round`` over ``serving.admit`` (classification, cache
+        lookups, the ``reset_lanes`` dispatch), ``serving.dispatch`` (the
+        engine chunk's dispatch), ``serving.sync`` (the ``peek`` in which
+        the host waits on the device) and ``serving.harvest`` with one
+        ``serving.take_row`` per finished lane.
         """
         if self._closed:
             raise ServerClosed("step() on a closed server")
-        done = self._admit()
+        t0 = timer.now() if self._time_rounds else 0.0
+        self._round_wait_s = 0.0
+        with self._tracer.span("serving.round", cat="step", tid="scheduler"):
+            done = self._round()
+        if self._time_rounds:
+            self.metrics.record_round(timer.now() - t0 - self._round_wait_s)
+        return done
+
+    def _round(self) -> list[Request]:
+        with self._tracer.span("serving.admit", cat="step", tid="scheduler"):
+            done = self._admit()
         busy = self.busy_lanes
         if self._tracer.enabled:
             self._tracer.counter("scheduler load", {
@@ -646,8 +687,7 @@ class ContinuousBatcher:
             self.metrics.record_step(0, 0)
             return done
         trips_before = self._trips
-        with self._tracer.span("step", cat="step", tid="scheduler", busy=busy):
-            peeked = self._advance_and_peek()
+        peeked = self._advance_and_peek(busy)
         if peeked is None:
             # recovery hook rebuilt the engine: nothing advanced this round
             self.metrics.record_step(busy, 0)
@@ -660,39 +700,48 @@ class ContinuousBatcher:
             if self._lane_req[lane] is not None and not active[lane]
         ]
         if finished:
-            now = self.clock()
-            for lane in finished:
-                req = self._lane_req[lane]
-                row = self.backend.take_row(self.state, lane)
-                if row.flags.writeable:  # shared with followers/retention:
-                    row.flags.writeable = False  # mutation must fail loudly
-                if not self._accept_row(req, lane, row, now):
-                    continue  # quarantined: the hook owns the bookkeeping
-                req.t_completed = now
-                req.phases = int(phases[lane])
-                req.dist = row
-                if self.cache is not None and req.effective_target is None:
-                    # point rows never enter the cache: past the pruning
-                    # bound they are partial, and the cache contract is
-                    # "full solve for this source". (_inflight holds no
-                    # entry for point lanes either — popping here keyed on
-                    # source would evict a concurrent full solve's entry.)
-                    self.cache.put(self._gkey, self.criterion, req.source,
-                                   req.dist, now=now)
-                    self._inflight.pop(req.source, None)
-                self._lane_req[lane] = None
-                self._finish(req)
-                done.append(req)
-                self._tracer.end(f"src {req.source}", cat="request",
-                                 tid=f"lane {lane}", phases=int(phases[lane]))
-                for f in self._followers.pop(lane, ()):
-                    f.t_completed = now
-                    f.phases = 0
-                    f.dist = req.dist
-                    self._finish(f)
-                    done.append(f)
+            with self._tracer.span("serving.harvest", cat="step",
+                                   tid="scheduler"):
+                self._harvest(finished, phases, done)
         self.metrics.record_step(busy, self._trips - trips_before)
         return done
+
+    def _harvest(self, finished, phases, done: list[Request]) -> None:
+        """Read out the finished lanes, complete their requests and their
+        followers (into ``done``) and free the lanes."""
+        now = self.clock()
+        for lane in finished:
+            req = self._lane_req[lane]
+            with self._tracer.span("serving.take_row", cat="step",
+                                   tid="scheduler", lane=lane):
+                row = self._wait(self.backend.take_row, self.state, lane)
+            if row.flags.writeable:  # shared with followers/retention:
+                row.flags.writeable = False  # mutation must fail loudly
+            if not self._accept_row(req, lane, row, now):
+                continue  # quarantined: the hook owns the bookkeeping
+            req.t_completed = now
+            req.phases = int(phases[lane])
+            req.dist = row
+            if self.cache is not None and req.effective_target is None:
+                # point rows never enter the cache: past the pruning
+                # bound they are partial, and the cache contract is
+                # "full solve for this source". (_inflight holds no
+                # entry for point lanes either — popping here keyed on
+                # source would evict a concurrent full solve's entry.)
+                self.cache.put(self._gkey, self.criterion, req.source,
+                               req.dist, now=now)
+                self._inflight.pop(req.source, None)
+            self._lane_req[lane] = None
+            self._finish(req)
+            done.append(req)
+            self._tracer.end(f"src {req.source}", cat="request",
+                             tid=f"lane {lane}", phases=int(phases[lane]))
+            for f in self._followers.pop(lane, ()):
+                f.t_completed = now
+                f.phases = 0
+                f.dist = req.dist
+                self._finish(f)
+                done.append(f)
 
     def drain(self, max_steps: int | None = None) -> list[Request]:
         """Step until queue and lanes are empty; returns the completions.

@@ -29,7 +29,6 @@ from repro.obs.registry import Histogram, MetricsRegistry, prom_name
 from repro.obs.tracer import (
     NULL_TRACER,
     Tracer,
-    _NULL_SPAN,
     load_trace,
     validate_events,
     validate_trace_file,
@@ -203,13 +202,71 @@ def test_cli_dashboard_portfolio_view(tmp_path, capsys):
 
 
 def test_disabled_tracer_is_inert():
-    assert NULL_TRACER.span("x") is _NULL_SPAN  # shared, no allocation
+    with NULL_TRACER.span("x"):  # a profiler annotation only
+        pass
     NULL_TRACER.begin("x")
     NULL_TRACER.end("x")
     NULL_TRACER.instant("x")
     NULL_TRACER.counter("x", {"v": 1})
     NULL_TRACER.name_thread("t", "thread")
     assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
+
+
+# the golden tracer's export, as the tracer wrote it before its spans also
+# became profiler annotations: the Chrome sink must not change
+GOLDEN_EVENTS = [
+    {"ph": "M", "name": "thread_name", "pid": "repro", "tid": "lane 0",
+     "ts": 0, "args": {"name": "serving lane 0"}},
+    {"ph": "M", "name": "thread_name", "pid": "repro", "tid": "scheduler",
+     "ts": 0, "args": {"name": "scheduler"}},
+    {"ph": "B", "name": "src 7", "cat": "request", "pid": "repro",
+     "tid": "lane 0", "ts": 1000.0, "args": {"source": 7}},
+    {"ph": "X", "name": "step", "cat": "step", "pid": "repro",
+     "tid": "scheduler", "ts": 2000.0, "dur": 3000.0, "args": {"busy": 1}},
+    {"ph": "X", "name": "chunk", "cat": "chunk", "pid": "repro",
+     "tid": "scheduler", "ts": 3000.0, "dur": 1000.0},
+    {"ph": "C", "name": "scheduler load", "cat": "default", "pid": "repro",
+     "tid": "counters", "ts": 6000.0,
+     "args": {"queue_depth": 3, "busy_lanes": 1}},
+    {"ph": "i", "name": "cache hit", "cat": "request", "pid": "repro",
+     "tid": "scheduler", "ts": 7000.0, "s": "t", "args": {"source": 7}},
+    {"ph": "E", "name": "src 7", "cat": "request", "pid": "repro",
+     "tid": "lane 0", "ts": 8000.0, "args": {"phases": 12}},
+]
+
+
+def _profiled_host_events(log_dir) -> set:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_spans_are_profiler_annotations_enabled_or_not(tmp_path):
+    """Both sinks: a disabled tracer's span still enters the profiler's
+    annotation and keeps no Chrome event; an enabled tracer's span is
+    annotated too, and its Chrome export is the one it always was."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with NULL_TRACER.span("obs.test.disabled", cat="step", busy=1):
+            pass
+        tr = _golden_tracer()
+    finally:
+        jax.profiler.stop_trace()
+    assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
+    assert tr.events() == GOLDEN_EVENTS
+    names = _profiled_host_events(str(tmp_path))
+    assert {"obs.test.disabled", "step", "chunk"} <= names
+    # instants, counters and the request B/E spans stay Chrome-only
+    assert not names & {"src 7", "scheduler load", "cache hit"}
 
 
 def test_tracer_event_bound_counts_drops():
@@ -380,4 +437,6 @@ def test_observability_bundle_modes():
     with on.tracer.span("s"):
         pass
     assert len(on.tracer.events()) == 1
-    assert off.tracer.span("s") is _NULL_SPAN
+    with off.tracer.span("s"):
+        pass
+    assert len(off.tracer) == 0 and off.tracer.events() == []
