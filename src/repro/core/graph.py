@@ -198,7 +198,7 @@ class EllSlice(NamedTuple):
 
     ``rows[i]`` is the vertex that slice-row ``i`` belongs to; a *split*
     heavy vertex contributes several rows (same ``rows`` id, disjoint edge
-    chunks), merged back by the consumer's scatter-min — exact, because f32
+    chunks), merged back through :class:`SlicedEll`'s plan — exact, because f32
     ``min`` has no rounding, so the merge is bit-identical to the padded
     single-row reduction in any order.
     """
@@ -218,17 +218,24 @@ class SlicedEll(NamedTuple):
     count instead of ``n * D_max``. Zero-degree vertices appear in no slice
     (the consumer's +inf merge identity is exactly their empty-min value).
 
-    ``merge_idx`` turns the slice->vertex merge into a *gather*: entry
-    ``[v, c]`` is the position of v's c-th slice-row in the row-major
-    concatenation of all slices (sentinel = total rows, where consumers
-    append one +inf slot), so ``merged[v] = min_c concat[merge_idx[v, c]]``
-    — the same take+min idiom as the kernels, instead of a scatter-min
-    (scatters serialise on CPU and row-conflict on TPU). C is the maximum
-    chunk count, 1 unless heavy rows split.
+    The slice->vertex merge is a two-level *gather* plan over the row-major
+    concatenation of all slices with one +inf slot appended (position
+    ``total``, the sentinel). ``merge_heavy[h, c]`` is the position of the
+    c-th slice-row of the h-th *split* vertex (one with more than one row;
+    padded with the sentinel), so its heavy min is
+    ``min_c concat[merge_heavy[h, c]]``. ``merge_first[v]`` is the position
+    of v's only row, the sentinel when v has none, or ``total + 1 + h``
+    when v is the h-th split vertex: the slot of its heavy min once those
+    are appended after the +inf slot. Every vertex pays one gather and only
+    the H split vertices pay for C, the largest chunk count; H = 0 (no
+    vertex split) leaves the single ``(n,)`` gather. Gathers, not a
+    scatter-min (scatters serialise on CPU and row-conflict on TPU); f32
+    ``min`` is exact in any order.
     """
 
     slices: tuple[EllSlice, ...]
-    merge_idx: jax.Array  # (n, C) int32 positions into concat(slices)+[inf]
+    merge_heavy: jax.Array  # (H, C) int32 positions into concat(slices)+[inf]
+    merge_first: jax.Array  # (n,) int32 positions into that + H heavy mins
 
     @property
     def widths(self) -> tuple[int, ...]:
@@ -237,6 +244,16 @@ class SlicedEll(NamedTuple):
     @property
     def padded_slots(self) -> int:
         return sum(int(s.cols.size) for s in self.slices)
+
+    @property
+    def split_vertices(self) -> int:
+        """H: vertices whose adjacency is split over more than one row."""
+        return int(self.merge_heavy.shape[0])
+
+    @property
+    def merge_slots(self) -> int:
+        """Entries one merge gathers per vector: n + H * C."""
+        return int(self.merge_first.shape[0]) + int(self.merge_heavy.size)
 
 
 def default_slice_boundaries(deg: np.ndarray, pad_multiple: int = 8,
@@ -328,13 +345,22 @@ def _build_ell_sliced(from_ids, to_ids, w, n, pad_multiple, boundaries,
     total = all_rows.shape[0]
     occ = np.zeros(n, np.int64)
     np.add.at(occ, all_rows, 1)
-    c_max = max(int(occ.max()) if occ.size else 1, 1)
-    merge_idx = np.full((n, c_max), total, np.int32)
     order = np.argsort(all_rows, kind="stable")
     srt = all_rows[order]
     rank = np.arange(total) - np.searchsorted(srt, srt, side="left")
-    merge_idx[srt, rank] = order
-    return SlicedEll(slices=tuple(slices), merge_idx=jnp.asarray(merge_idx))
+    merge_first = np.full(n, total, np.int64)
+    merge_first[srt[rank == 0]] = order[rank == 0]
+    heavy = np.nonzero(occ > 1)[0]
+    merge_first[heavy] = total + 1 + np.arange(heavy.size)
+    c_max = max(int(occ.max()) if occ.size else 1, 1)
+    merge_heavy = np.full((heavy.size, c_max), total, np.int32)
+    h_of = np.full(n, -1, np.int64)
+    h_of[heavy] = np.arange(heavy.size)
+    in_heavy = h_of[srt] >= 0
+    merge_heavy[h_of[srt[in_heavy]], rank[in_heavy]] = order[in_heavy]
+    return SlicedEll(slices=tuple(slices),
+                     merge_heavy=jnp.asarray(merge_heavy),
+                     merge_first=jnp.asarray(merge_first.astype(np.int32)))
 
 
 def _sliced_cache_key(pad_multiple, boundaries, split):

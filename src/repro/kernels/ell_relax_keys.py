@@ -269,8 +269,9 @@ def ell_relax_keys(dmask, ga, gb, gc, cols, ws, *, block_rows: int = 256,
 # reduction round; under the interpret machinery each launch carries real
 # emulation overhead, so a 3-bucket in|out phase pays 12 launches. These
 # variadic single-launch kernels run at grid=(1,) with every bucket's tiles
-# and the gather-merge plan resident, folding a whole scan — all buckets,
-# both dependent reductions, and the slice->vertex merges — into ONE launch.
+# and the two-level gather-merge plan resident, folding a whole scan — all
+# buckets, both dependent reductions, and the slice->vertex merges — into
+# ONE launch.
 # They are the sliced twins of the one-tile megakernel bodies above (no
 # predication, no dynamic stores) and are bit-identical to the per-bucket
 # decomposition. Compiled (Mosaic) runs keep the per-bucket tiled path —
@@ -278,15 +279,22 @@ def ell_relax_keys(dmask, ga, gb, gc, cols, ws, *, block_rows: int = 256,
 # regime (and the per-shard regime after vertex partitioning).
 
 
-def _merge_parts(parts, merge_idx, lead):
-    """(..., R_b) bucket partials -> (..., n) via the gather-merge plan.
+def _merge_parts(parts, merge_heavy, merge_first, lead):
+    """(..., R_b) bucket partials -> (..., n) via the two-level gather plan
+    of :class:`~repro.core.graph.SlicedEll`: the split vertices' rows are
+    min-reduced into H heavy slots appended after the +inf slot, then one
+    ``(n,)`` gather reads every vertex's row, sentinel or heavy slot.
 
     ``lead`` is the leading shape (parts may be empty: an edgeless graph
-    has no buckets, and every merge_idx entry reads the +inf slot)."""
+    has no buckets, and every merge_first entry reads the +inf slot).
+    ``merge_heavy`` is None where there is no split vertex (H = 0)."""
     flat = jnp.concatenate(
         parts + [jnp.full(lead + (1,), INF, jnp.float32)], axis=-1
     )
-    return jnp.min(jnp.take(flat, merge_idx, axis=-1), axis=-1)
+    if merge_heavy is not None and merge_heavy.shape[0]:
+        heavy = jnp.min(jnp.take(flat, merge_heavy, axis=-1), axis=-1)
+        flat = jnp.concatenate([flat, heavy], axis=-1)
+    return jnp.take(flat, merge_first, axis=-1)
 
 
 def _slice_mins(vec, slice_refs):
@@ -307,6 +315,25 @@ def _pad_back(vec_n, n_idx):
     return jnp.pad(vec_n, pad, constant_values=INF)
 
 
+def _plan_operands(sliced):
+    """BlockSpecs and operands of the merge plan: ``merge_first``, then
+    ``merge_heavy`` only where some vertex is split (a zero-size block
+    does not lower)."""
+    specs = [pl.BlockSpec(sliced.merge_first.shape, lambda: (0,))]
+    operands = [sliced.merge_first]
+    if sliced.split_vertices:
+        specs.append(pl.BlockSpec(sliced.merge_heavy.shape, lambda: (0, 0)))
+        operands.append(sliced.merge_heavy)
+    return specs, operands
+
+
+def _plan_refs(refs, n_plan):
+    """``(merge_heavy, merge_first, rest)`` from a kernel's refs that start
+    with the :func:`_plan_operands` blocks."""
+    heavy = refs[1][...] if n_plan == 2 else None
+    return heavy, refs[0][...], refs[n_plan:]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ell_sliced_gather_min_batch(vecs, sliced, *, interpret: bool | None = None):
     """One-launch sliced multi-vector gather-min: (V, B, n) row-mins of
@@ -318,15 +345,17 @@ def ell_sliced_gather_min_batch(vecs, sliced, *, interpret: bool | None = None):
     slices = tuple(s for s in sliced.slices if s.rows.shape[0])
     n_idx = -(-(n + 1) // 128) * 128
 
-    def kernel(vecs_ref, midx_ref, *refs):
+    plan_specs, plan = _plan_operands(sliced)
+
+    def kernel(vecs_ref, *refs):
+        heavy, first, refs = _plan_refs(refs, len(plan))
         slice_refs = [(refs[2 * i], refs[2 * i + 1]) for i in range(len(slices))]
         parts = _slice_mins(vecs_ref[...], slice_refs)
         out_ref = refs[-1]
-        out_ref[...] = _merge_parts(parts, midx_ref[...], (v, b))
+        out_ref[...] = _merge_parts(parts, heavy, first, (v, b))
 
-    in_specs = [pl.BlockSpec((v, b, n_idx), lambda: (0, 0, 0)),
-                pl.BlockSpec(sliced.merge_idx.shape, lambda: (0, 0))]
-    operands = [_pad_idx(vecs, n_idx), sliced.merge_idx]
+    in_specs = [pl.BlockSpec((v, b, n_idx), lambda: (0, 0, 0))] + plan_specs
+    operands = [_pad_idx(vecs, n_idx)] + plan
     for s in slices:
         in_specs += [pl.BlockSpec(s.cols.shape, lambda: (0, 0)),
                      pl.BlockSpec(s.ws.shape, lambda: (0, 0))]
@@ -355,25 +384,28 @@ def ell_sliced_relax_keys_batch(dmask, ga, gb, gc, sliced, *,
     slices = tuple(s for s in sliced.slices if s.rows.shape[0])
     n_idx = -(-(n + 1) // 128) * 128
 
-    def kernel(dmask_ref, ga_ref, gb_ref, gc_ref, midx_ref, *refs):
+    plan_specs, plan = _plan_operands(sliced)
+
+    def kernel(dmask_ref, ga_ref, gb_ref, gc_ref, *refs):
+        heavy, first, refs = _plan_refs(refs, len(plan))
         slice_refs = [(refs[2 * i], refs[2 * i + 1]) for i in range(len(slices))]
         upd_ref, keys_ref = refs[-2], refs[-1]
-        midx = midx_ref[...]
-        upd = _merge_parts(_slice_mins(dmask_ref[...], slice_refs), midx, (b,))
+        upd = _merge_parts(_slice_mins(dmask_ref[...], slice_refs), heavy,
+                           first, (b,))
         fin = _pad_back(jnp.where(upd < INF, 0.0, INF), n_idx)
         gate = jnp.minimum(
             ga_ref[...], jnp.minimum(gb_ref[...], gc_ref[...] + fin[None])
         )
-        keys_ref[...] = _merge_parts(_slice_mins(gate, slice_refs), midx, (k, b))
+        keys_ref[...] = _merge_parts(_slice_mins(gate, slice_refs), heavy,
+                                     first, (k, b))
         upd_ref[...] = upd
 
     in_specs = [pl.BlockSpec((b, n_idx), lambda: (0, 0)),
                 pl.BlockSpec((k, b, n_idx), lambda: (0, 0, 0)),
                 pl.BlockSpec((k, b, n_idx), lambda: (0, 0, 0)),
-                pl.BlockSpec((k, b, n_idx), lambda: (0, 0, 0)),
-                pl.BlockSpec(sliced.merge_idx.shape, lambda: (0, 0))]
+                pl.BlockSpec((k, b, n_idx), lambda: (0, 0, 0))] + plan_specs
     operands = [_pad_idx(dmask, n_idx), _pad_idx(ga, n_idx),
-                _pad_idx(gb, n_idx), _pad_idx(gc, n_idx), sliced.merge_idx]
+                _pad_idx(gb, n_idx), _pad_idx(gc, n_idx)] + plan
     for s in slices:
         in_specs += [pl.BlockSpec(s.cols.shape, lambda: (0, 0)),
                      pl.BlockSpec(s.ws.shape, lambda: (0, 0))]
@@ -404,22 +436,25 @@ def ell_sliced_keys_dep_batch(gates, dga, dgb, sliced, *, dep_idx: int = 0,
     slices = tuple(s for s in sliced.slices if s.rows.shape[0])
     n_idx = -(-(n + 1) // 128) * 128
 
-    def kernel(gates_ref, dga_ref, dgb_ref, midx_ref, *refs):
+    plan_specs, plan = _plan_operands(sliced)
+
+    def kernel(gates_ref, dga_ref, dgb_ref, *refs):
+        heavy, first, refs = _plan_refs(refs, len(plan))
         slice_refs = [(refs[2 * i], refs[2 * i + 1]) for i in range(len(slices))]
         keys_ref = refs[-1]
-        midx = midx_ref[...]
-        keys0 = _merge_parts(_slice_mins(gates_ref[...], slice_refs), midx, (k0, b))
+        keys0 = _merge_parts(_slice_mins(gates_ref[...], slice_refs), heavy,
+                             first, (k0, b))
         dep = _pad_back(keys0[dep_idx], n_idx)
         gate = jnp.minimum(dga_ref[...], dgb_ref[...] + dep)
-        dep_key = _merge_parts(_slice_mins(gate, slice_refs), midx, (b,))
+        dep_key = _merge_parts(_slice_mins(gate, slice_refs), heavy, first,
+                               (b,))
         keys_ref[...] = jnp.concatenate([keys0, dep_key[None]], axis=0)
 
     in_specs = [pl.BlockSpec((k0, b, n_idx), lambda: (0, 0, 0)),
                 pl.BlockSpec((b, n_idx), lambda: (0, 0)),
-                pl.BlockSpec((b, n_idx), lambda: (0, 0)),
-                pl.BlockSpec(sliced.merge_idx.shape, lambda: (0, 0))]
+                pl.BlockSpec((b, n_idx), lambda: (0, 0))] + plan_specs
     operands = [_pad_idx(gates, n_idx), _pad_idx(dga, n_idx),
-                _pad_idx(dgb, n_idx), sliced.merge_idx]
+                _pad_idx(dgb, n_idx)] + plan
     for s in slices:
         in_specs += [pl.BlockSpec(s.cols.shape, lambda: (0, 0)),
                      pl.BlockSpec(s.ws.shape, lambda: (0, 0))]

@@ -16,10 +16,10 @@ sentinel/alignment convention).
 Adjacency layouts: wrappers taking an ``ell`` argument accept either the
 padded ``(cols, ws)`` pair (``to_ell_in``) or a degree-sliced
 ``SlicedEll`` (``to_ell_in_sliced``) — sliced layouts run a one-launch
-variadic megascan under interpret (all buckets + the gather-based
-``merge_idx`` merge inside one kernel) or one tiled call per bucket on
-compiled backends (split heavy rows fold in the merge; f32 min is exact,
-so both layouts return bit-identical results).
+variadic megascan under interpret (all buckets + the two-level gather
+merge, ``merge_heavy`` then ``merge_first``, inside one kernel) or one
+tiled call per bucket on compiled backends (split heavy rows fold in the
+merge; f32 min is exact, so both layouts return bit-identical results).
 
 The production engines (``repro.core.static_engine`` stepper and everything
 built on it) consume the batched 2-D entry points; the 1-D
@@ -77,10 +77,10 @@ def pad_lane_batch(x: jax.Array, fill=INF) -> jax.Array:
     return jnp.full((b, lane_pad), fill, jnp.float32).at[:, :n].set(x)
 
 
-# The one slice->vertex merge implementation (concat + inf sentinel +
-# take(merge_idx) + min) is ell_relax_keys._merge_parts; the sliced kernel
-# bodies and this host-side path must share it so the merge convention can
-# never diverge between them.
+# The one slice->vertex merge implementation (concat + inf sentinel, the
+# split vertices' take(merge_heavy) + min, one take(merge_first)) is
+# ell_relax_keys._merge_parts; the sliced kernel bodies and this host-side
+# path must share it so the merge convention can never diverge between them.
 
 
 def relax_settled(
@@ -189,6 +189,13 @@ def gather_min_batch_sliced(
         if s.rows.shape[0] == 0:
             continue  # zero rows: contributes nothing to the concat order
         if not use_pallas:
+            if parts:
+                # each bucket's gather after the previous bucket's row-min,
+                # narrowest first: left free, XLA's TPU scheduler starts
+                # with the widest and spills the gather source to HBM for
+                # the next (on a v5e at Graph500 scale 16, the 128-wide
+                # bucket's gather then takes 4.2 ms per phase, not 0.9)
+                vecs, _ = jax.lax.optimization_barrier((vecs, parts[-1]))
             parts.append(kref.ell_gather_min_batch_ref(vecs, s.cols, s.ws))
             continue
         br = block_rows
@@ -200,7 +207,7 @@ def gather_min_batch_sliced(
         parts.append(ell_gather_min_batch(
             vecs, s.cols, s.ws, block_rows=br, interpret=interpret
         ))
-    return _merge_parts(parts, sliced.merge_idx, (v, b))
+    return _merge_parts(parts, sliced.merge_heavy, sliced.merge_first, (v, b))
 
 
 def static_thresholds_batch(

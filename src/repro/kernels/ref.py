@@ -139,7 +139,8 @@ def ell_sliced_gather_min_batch_ref(vecs, sliced):
         for s in sliced.slices
         if s.rows.shape[0]
     ]
-    return _merge_parts(parts, sliced.merge_idx, vecs.shape[:-1])
+    return _merge_parts(parts, sliced.merge_heavy, sliced.merge_first,
+                        vecs.shape[:-1])
 
 
 def ell_sliced_relax_keys_batch_ref(dmask, ga, gb, gc, sliced):

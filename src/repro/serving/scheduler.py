@@ -67,7 +67,7 @@ from collections import deque
 import jax
 import numpy as np
 
-from repro.core.graph import Graph
+from repro.core.graph import Graph, SlicedEll
 from repro.core.static_engine import EMPTY_LANE, KEEP_LANE
 from repro.obs import NULL_TRACER, Observability, timer
 from repro.serving.backends import EngineBackend, StaticBackend
@@ -92,6 +92,23 @@ class ServerClosed(RuntimeError):
 class Backpressure(RuntimeError):
     """submit() rejected: the pending backlog is at ``max_pending`` and the
     arrival outranks nothing it could displace."""
+
+
+def _record_layout(registry, backend) -> None:
+    """Gauge the merge plan of a backend's degree-sliced incoming view:
+    ``layout.split_vertices`` (H) and ``layout.merge_slots`` (entries one
+    merge gathers per lane). A padded backend records neither; wrappers
+    that hold their backend as ``inner`` are looked through."""
+    while backend is not None and getattr(backend, "ell", None) is None:
+        backend = getattr(backend, "inner", None)
+    ell = getattr(backend, "ell", None)
+    if isinstance(ell, SlicedEll):
+        registry.gauge("layout.split_vertices",
+                       "vertices split over more than one slice-row"
+                       ).set(ell.split_vertices)
+        registry.gauge("layout.merge_slots",
+                       "entries one slice->vertex merge gathers per lane"
+                       ).set(ell.merge_slots)
 
 
 class ContinuousBatcher:
@@ -153,6 +170,8 @@ class ContinuousBatcher:
         occupancy span (B/E), the ``serving.*`` spans of each round,
         admission instants, and queue-depth/busy-lane counter tracks —
         export with ``obs.tracer.export(path)`` and open in Perfetto.
+        Over a degree-sliced backend it also records the gauges
+        ``layout.split_vertices`` and ``layout.merge_slots`` once, here.
         Default None: no Chrome events, no registry traffic, the same
         scheduling. The ``serving.*`` spans are profiler annotations
         either way (see :meth:`step`).
@@ -235,6 +254,8 @@ class ContinuousBatcher:
             else obs.registry.gauge("serving.queue_depth",
                                     "engine-bound requests waiting for a lane")
         )
+        if obs is not None:
+            _record_layout(obs.registry, backend)
         self.max_pending = None if max_pending is None else int(max_pending)
         self.cache_max_age = (
             None if cache_max_age is None else float(cache_max_age)

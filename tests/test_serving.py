@@ -449,3 +449,42 @@ def test_serving_entry_points_reach_no_pallas_kernel_by_default():
             assert calls, "the recorder saw no kernel on the opt-in path"
     finally:
         jax.clear_caches()  # drop programs traced with the fake kernels
+
+
+class _InnerProxy:
+    """A wrapper that holds its backend as ``inner`` (as the portfolio and
+    timing proxies do) and exposes no adjacency of its own."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.g = inner.g
+        self.criterion = inner.criterion
+        self.point_queries = inner.point_queries
+
+    def init(self, lanes):
+        return self.inner.init(lanes)
+
+
+@pytest.mark.parametrize("layout,wrap", [
+    ("sliced", False), ("sliced", True), ("padded", False)])
+def test_batcher_gauges_the_sliced_merge_plan(layout, wrap):
+    """With ``obs``, a server over a degree-sliced backend records the merge
+    plan's size once, at construction; a padded backend records neither."""
+    from repro.graphs import kronecker
+    from repro.obs import Observability
+
+    g = kronecker(7, seed=21)
+    backend = StaticBackend(g, layout=layout)
+    obs = Observability.enabled()
+    ContinuousBatcher(g, lanes=2, obs=obs,
+                      backend=_InnerProxy(backend) if wrap else backend)
+    names = set(obs.registry.names())
+    if layout == "padded":
+        assert not {"layout.split_vertices", "layout.merge_slots"} & names
+        return
+    assert backend.ell.split_vertices > 0  # the hubs split
+    assert (obs.registry.get("layout.split_vertices").value
+            == backend.ell.split_vertices)
+    assert (obs.registry.get("layout.merge_slots").value
+            == backend.ell.merge_slots
+            == g.n + backend.ell.merge_heavy.size)

@@ -23,6 +23,8 @@ from repro.core.static_engine import (
     step_batch,
 )
 from repro.graphs import grid_road, kronecker, uniform_gnp
+from repro.kernels.ell_relax_keys import _merge_parts
+from repro.kernels.ops import gather_min_batch_sliced
 
 # the "property" sweep: bucket boundaries x split thresholds, including
 # degenerate single-bucket and aggressive row-splitting configurations
@@ -52,6 +54,65 @@ def test_sliced_layouts_bit_identical_to_padded(boundaries, split, crit):
                                   np.asarray(want.sum_fringe))
     np.testing.assert_array_equal(np.asarray(got.relax_edges),
                                   np.asarray(want.relax_edges))
+
+
+@pytest.mark.parametrize("boundaries,split", LAYOUT_CASES)
+@pytest.mark.parametrize("side", ["in", "out"])
+def test_merge_plan_heavy_table_and_first_gather(boundaries, split, side):
+    """The two-level merge plan: one heavy row per split vertex listing
+    exactly its slice-rows, and one ``merge_first`` entry per vertex that
+    points at its only row, the +inf sentinel, or its heavy slot."""
+    g = kronecker(7, seed=21)
+    build = to_ell_in_sliced if side == "in" else to_ell_out_sliced
+    se = build(g, boundaries=boundaries, split=split)
+    rows = np.concatenate([np.asarray(s.rows) for s in se.slices])
+    total = rows.size
+    occ = np.bincount(rows, minlength=g.n)
+    split_v = np.nonzero(occ > 1)[0]
+    heavy = np.asarray(se.merge_heavy)
+    first = np.asarray(se.merge_first)
+    h, c = heavy.shape
+    assert h == split_v.size == se.split_vertices
+    assert c == max(int(occ.max()), 1)
+    assert first.shape == (g.n,)
+    assert se.merge_slots == g.n + h * c
+    if boundaries == (8,):
+        assert h > 0  # every hub splits
+    for i, v in enumerate(split_v):
+        pos = heavy[i][heavy[i] != total]
+        assert sorted(pos.tolist()) == np.nonzero(rows == v)[0].tolist()
+        assert first[v] == total + 1 + i
+    single = np.nonzero(occ == 1)[0]
+    np.testing.assert_array_equal(rows[first[single]], single)
+    assert (first[occ == 0] == total).all()
+    # the merge reads each vertex's min over its own rows, exactly
+    vals = np.random.default_rng(3).random((2, total)).astype(np.float32)
+    want = np.full((2, g.n), np.inf, np.float32)
+    np.minimum.at(want, (slice(None), rows), vals)
+    got = _merge_parts([vals], se.merge_heavy, se.merge_first, (2,))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("make", [
+    lambda: grid_road(11, 9, seed=55),
+    lambda: uniform_gnp(200, 0.01, seed=7),
+])
+def test_merge_plan_without_split_vertices(make):
+    """Where the largest degree fits the widest bucket no vertex splits:
+    H = 0, the merge is the one ``(n,)`` gather, and the interpret
+    megascan (which then carries no heavy table) agrees with the XLA form."""
+    g = make()
+    se = to_ell_in_sliced(g)
+    assert se.split_vertices == 0 and se.merge_heavy.shape[0] == 0
+    assert se.merge_slots == g.n
+    vecs = np.random.default_rng(1).random((2, 3, g.n)).astype(np.float32)
+    want = gather_min_batch_sliced(vecs, se)
+    got = gather_min_batch_sliced(vecs, se, use_pallas=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    srcs = np.asarray([0, g.n - 1], np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(run_phased_static_batch(g, srcs, ell=se).dist),
+        np.asarray(run_phased_static_batch(g, srcs).dist))
 
 
 def test_sliced_stepper_chunking_and_reset():

@@ -8,6 +8,10 @@ time. The topology is described inside a fixture, never at import: only the
 test worker that runs this file loads the TPU library.
 """
 import dataclasses
+import importlib.util
+import json
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +24,12 @@ from repro.core.distributed import (
     init_sharded_batch_state,
     shard_graph_batch,
 )
-from repro.core.graph import to_ell_in, to_ell_in_sliced, to_ell_out_sliced
+from repro.core.graph import (
+    from_coo,
+    to_ell_in,
+    to_ell_in_sliced,
+    to_ell_out_sliced,
+)
 from repro.core.static_engine import (
     EMPTY_LANE,
     _step_batch_donate,
@@ -30,6 +39,7 @@ from repro.graphs import grid_road, uniform_gnp
 
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 LANES = 8
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +118,54 @@ def test_sliced_dynamic_criterion_step_compiles_for_v5e(one_chip):
                                     to_ell_out_sliced(g), state, one_chip)
     assert "tpu_custom_call" not in compiled.as_text()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+def _graph500_s16():
+    """The benchmark's Graph500 scale-16 graph, from its own generator and
+    configuration file (loaded by path: the generator needs only numpy)."""
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_graphs", ROOT / "perfbench" / "graphs.py")
+    graphs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(graphs)
+    cfg = json.loads(
+        (ROOT / "perfbench" / "configs" / "graph500-s16.json").read_text())
+    src, dst, w, n, _ = graphs.build(cfg, seed=0)
+    return from_coo(src, dst, w, n), cfg["criterion"]
+
+
+def _gather_sources(text):
+    """``(gather, layout of the array it gathers from)`` for every gather
+    fusion of a compiled program's HLO text."""
+    layouts = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", text,
+                              re.M))
+    return [(m[0], layouts.get(m[1], "")) for m in re.findall(
+        r"^\s*(%[\w.\-]+) = \S+ fusion\((%[\w.\-]+),.*kind=kCustom.*/gather\"",
+        text, re.M)]
+
+
+def test_graph500_sliced_step_merges_through_the_heavy_table(one_chip):
+    """The benchmark's served step on Graph500 scale 16: XLA only, it fits,
+    and the slice->vertex merge gathers n + H * C entries per lane, so no
+    array of the program holds a multiple of n * C elements. Every gather
+    reads its source from VMEM (memory space S(1)): a bucket gather that
+    reads the distance rows from HBM runs about 5x slower on the chip."""
+    g, criterion = _graph500_s16()
+    ell = to_ell_in_sliced(g)
+    heavy, chunks = ell.merge_heavy.shape
+    assert 0 < heavy < g.n and chunks > 1  # hubs split, most vertices not
+    state = init_batch_state(g, np.full(LANES, EMPTY_LANE, np.int32),
+                             criterion=criterion)
+    compiled = _compile_served_step(g, ell, None, state, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+    dense = g.n * chunks
+    sizes = {int(np.prod([int(d) for d in dims.split(",") if d]))
+             for dims in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", text)}
+    assert not [s for s in sizes if s and s % dense == 0]
+    sources = _gather_sources(text)
+    assert len(sources) >= len(ell.slices) + 2  # buckets, heavy, first
+    assert [g for g, layout in sources if "S(1)" not in layout] == []
 
 
 def test_sharded_step_compiles_for_four_chips(topo, road):
