@@ -25,6 +25,7 @@ class Run:
     ref_rows: dict  # source -> float64 reference row
     reference: object  # reference.Reference
     peak: dict  # peaks.PEAKS entry of the device
+    chips: int = 1  # chips the cell serves on
 
 
 def _in_window(run: Run):
@@ -94,6 +95,25 @@ def phase_device_ms(run: Run):
     return 1e3 * run.trace.busy_s / run.window.trips
 
 
+def _collective_ms_per_phase(run: Run, field: str):
+    t = run.trace
+    if t is None or t.collective_s <= 0 or not run.window.trips:
+        return None
+    return 1e3 * getattr(t, field) / run.window.trips
+
+
+def collective_ms_per_phase(run: Run):
+    """Device time with a collective in flight, per chip, over the phases
+    advanced; None where the window holds no collective op."""
+    return _collective_ms_per_phase(run, "collective_s")
+
+
+def collective_exposed_ms_per_phase(run: Run):
+    """The part of it in which the chip runs the collective and nothing
+    else (the collectives' self time), over the phases advanced."""
+    return _collective_ms_per_phase(run, "collective_exposed_s")
+
+
 def solve_bytes(reached_arcs: int, n: int, lanes: int) -> float:
     """Least HBM bytes of one full-row solve: each arc reached (4-byte id,
     4-byte weight) read once and shared by the ``lanes`` lanes of a phase,
@@ -103,7 +123,8 @@ def solve_bytes(reached_arcs: int, n: int, lanes: int) -> float:
 
 def solve_hbm_roofline(run: Run):
     """Least time of the full-row engine solves answered in the traced
-    window at the HBM peak, over the device's busy time in it."""
+    window at the HBM peak of the cell's chips, over the busy time per chip
+    in it."""
     if run.trace is None or run.trace.busy_s <= 0:
         return None
     rows = [r for r in _engine(_in_window(run)) if r.target is None]
@@ -111,4 +132,5 @@ def solve_hbm_roofline(run: Run):
         return None
     need = sum(solve_bytes(run.reference.reached_arcs(run.ref_rows[r.source]),
                            run.n, run.lanes) for r in rows)
-    return 100.0 * need / run.peak["hbm_bytes_per_s"] / run.trace.busy_s
+    return (100.0 * need / (run.chips * run.peak["hbm_bytes_per_s"])
+            / run.trace.busy_s)

@@ -3,12 +3,13 @@
     python3 perfbench/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 The run builds the cell's graph on the host, its vertices relabelled by
-``--seed`` (``perfbench/graphs.py``), serves it
-through the program's ``ContinuousBatcher`` over a ``StaticBackend`` on one
-chip, warms the cell's programs, offers the cell's traffic for ``--seconds``
-of wall time, waits for the answers due, and compares every answer with the
-benchmark's own reference. ``--trace 1`` profiles the window and reports
-the cell's per-layer metrics; ``--trace 0`` its end-to-end metrics.
+``--seed`` (``perfbench/graphs.py``), serves it through the program's
+``ContinuousBatcher`` over the backend that the cell's ``chips`` picks
+(:func:`make_backend`), warms the cell's programs, offers the cell's
+traffic for ``--seconds`` of wall time, waits for the answers due, and
+compares every answer with the benchmark's own reference. ``--trace 1``
+profiles the window and reports the cell's per-layer metrics; ``--trace 0``
+its end-to-end metrics.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
@@ -25,6 +26,7 @@ T_START = time.perf_counter()  # set-up is counted from here
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -56,6 +58,42 @@ def check_machine(cell, devices, peaks) -> None:
         raise NoChip(f"no peaks for device kind {devices[0].device_kind!r}")
 
 
+# What ShardedBackend serves of a configuration key its constructor does not
+# take, and why another value is refused. Once it takes the key, the
+# configuration's value goes through to it.
+SHARDED_FIXED = {
+    "layout": ("coo", "ShardedBackend serves its COO push with one exchange "
+                      "per phase, layout 'coo', only"),
+    "point_queries": (False, "ShardedBackend does not support s->t target "
+                             "lanes; serve point queries through a "
+                             "point-capable StaticBackend"),
+}
+SHARDED_AXIS = "v"
+
+
+def make_backend(g, cfg, chips: int):
+    """The cell's backend over graph ``g``: ``StaticBackend`` on one chip;
+    on more, ``ShardedBackend`` over a 1-D mesh of the first ``chips``
+    devices. The configuration's ``layout``, ``criterion`` and
+    ``point_queries`` reach either unchanged."""
+    import jax
+
+    from repro.serving import ShardedBackend, StaticBackend
+
+    kw = {"layout": cfg["layout"], "criterion": cfg["criterion"],
+          "point_queries": bool(cfg["point_queries"])}
+    if chips == 1:
+        return StaticBackend(g, **kw)
+    takes = inspect.signature(ShardedBackend).parameters
+    for key, (only, why) in SHARDED_FIXED.items():
+        if key not in takes and kw.pop(key) != only:
+            raise ValueError(f"{why}; the configuration asks for "
+                             f"{key}={cfg[key]!r}")
+    mesh = jax.make_mesh((chips,), (SHARDED_AXIS,),
+                         devices=jax.devices()[:chips])
+    return ShardedBackend(g, mesh, (SHARDED_AXIS,), **kw)
+
+
 def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
              peak: dict, trace_dir: Path | None = None, wrap_backend=None,
              substitute=None):
@@ -74,7 +112,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     from repro.compile_cache import use_compile_cache
     from repro.core.graph import from_coo
     from repro.obs import timer
-    from repro.serving import ContinuousBatcher, DistCache, StaticBackend
+    from repro.serving import ContinuousBatcher, DistCache
 
     use_compile_cache()
     # every program of the run, however quick to compile, is kept
@@ -92,8 +130,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     t_imports = timer.now() - t_start
     src, dst, w, n, labels = graphs.build(cfg, seed)
     g = from_coo(src, dst, w, n)
-    inner = StaticBackend(g, layout=cfg["layout"], criterion=cfg["criterion"],
-                          point_queries=bool(cfg["point_queries"]))
+    inner = make_backend(g, cfg, cell.chips)
     if wrap_backend is not None:
         inner = wrap_backend(inner)
     backend = serve.TimedBackend(inner, timer.now)
@@ -146,11 +183,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     ref_s = timer.now() - t_ref
     summary = None
     if trace:
-        summary = trace_mod.summarize_file(trace_mod.find_xplane(trace_dir))
+        summary = trace_mod.summarize_file(trace_mod.find_xplane(trace_dir),
+                                           chips=cell.chips)
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = layers.Run(setup_s=setup_s, window=window, drain_end=drain_end,
                      n=n, lanes=int(cfg["lanes"]), trace=summary,
-                     ref_rows=rows64, reference=ref, peak=peak)
+                     ref_rows=rows64, reference=ref, peak=peak,
+                     chips=cell.chips)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = m.read(run)
@@ -174,6 +213,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     lat = window.lateness_s
     info = {
         "graph": {"n": n, "arcs": int(src.size)},
+        "chips": cell.chips,
+        "memory_peak_bytes_by_chip": mem,
         "setup_s_at": {"imports": t_imports, "graph_and_layout": t_graph,
                        "warm_up": t_warm, "window_start": setup_s},
         "window_s": window.seconds,
@@ -189,14 +230,16 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     return result, checks, info
 
 
-def main(argv=None) -> int:
+def main(argv=None, root: Path = ROOT) -> int:
+    """The benchmark's command, over the ``BENCHMARK.json`` under ``root``
+    (a test's own tree may hold cells that the shipped one has not)."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    cell = load_cell(args.workload)
+    cell = load_cell(args.workload, root)
 
     import jax
 

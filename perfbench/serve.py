@@ -1,7 +1,7 @@
 """Drive ``ContinuousBatcher`` through one measured window.
 
 The entry point the window drives is the program's ``ContinuousBatcher``
-over its ``StaticBackend``, with the wall clock. ``TimedBackend`` is the
+over the cell's backend, with the wall clock. ``TimedBackend`` is the
 benchmark's own delegating proxy: it times the calls into the backend and
 puts a named host span around each, so that the scheduler's host time per
 chunk and the device's idle gaps can be told apart.
@@ -31,7 +31,7 @@ class TimedBackend:
         self.inner = inner
         self.g = inner.g
         self.criterion = inner.criterion
-        self.point_queries = inner.point_queries
+        self.point_queries = getattr(inner, "point_queries", False)
         self._clock = clock
         self.wall = dict.fromkeys(self.CALLS, 0.0)
         self.calls = dict.fromkeys(self.CALLS, 0)
@@ -197,13 +197,17 @@ def drain(server, window: Window, clock):
 def warm_up(make_server):
     """Compile the cell's served programs before the window, on a throwaway
     server over the same backend: admission (with an s->t lane where the
-    server takes them), one phase of the step, the peek and a harvest. The
-    chunk length is a traced operand, so one phase compiles the program
-    that the window runs with its default chunk."""
+    server takes them), one phase of the step and the peek, twice, and a
+    harvest. The chunk length is a traced operand, so one phase compiles
+    the program that the window runs with its default chunk."""
     server = make_server(phases_per_step=1)
     server.submit(0)
     if server.point_queries:
         server.submit(1 % server.backend.n, target=0)
+    server.step()
+    # a second round admits into and steps the state that the first
+    # returned: a mesh backend's is sharded where its fresh state is not
+    server.submit(2 % server.backend.n)
     server.step()
     server.backend.take_row(server.state, 0)
 

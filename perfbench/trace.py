@@ -1,7 +1,15 @@
 """Reduce a profiler trace (``.xplane.pb``) to the benchmark's numbers.
 
 - busy: the union of the intervals in which an op ran on a device, over
-  the traced window, averaged over the devices that ran any;
+  the traced window, averaged over the cell's chips (a chip that ran no op
+  counts as idle);
+- collectives: ops whose HLO kind is a collective (``COLLECTIVES``, with
+  their ``-start``/``-done`` forms). Their total time is the union of the
+  synchronous ops' intervals and of the spans from each ``-start`` to its
+  ``-done``, within the device's busy time; the exposed part is their self
+  time, in which the device does the collective and nothing else. What an
+  asynchronous pair's span holds besides is hidden under other ops. Both
+  are averaged over the cell's chips;
 - idle gaps: the complements of those intervals in the window, each named
   by the innermost host span (``TraceAnnotation``) covering its midpoint;
 - op top-list: device time by op name, counted as self time (an op's
@@ -15,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import glob
+import math
 import os
 import re
 from collections import defaultdict
@@ -22,15 +31,19 @@ from collections import defaultdict
 OPS_LINE = "XLA Ops"
 HOST_PREFIXES = ("bench.", "backend.")
 TOP = 10
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
 
 
 @dataclasses.dataclass
 class TraceSummary:
     window_s: float
-    busy_s: float  # mean over devices with ops
-    devices: int
+    busy_s: float  # mean over the cell's chips
+    devices: int  # devices that ran an op in the window
     device_ops: list  # [[name, seconds], ...] by self time, top first
     idle_gaps: list  # [[host span name, seconds], ...], largest first
+    collective_s: float = 0.0  # mean over the cell's chips
+    collective_exposed_s: float = 0.0  # its self time, the same mean
 
 
 def merge(intervals):
@@ -81,6 +94,52 @@ def self_times(events):
     return dict(tot)
 
 
+def collective_kind(name: str) -> str | None:
+    """The collective an op of :func:`op_name`'s form runs, with ``-start``
+    or ``-done`` where it is one half of an asynchronous pair; None for
+    any other op."""
+    kind = name.rsplit(" ", 1)[-1]
+    base = kind.removesuffix("-start").removesuffix("-done")
+    return kind if base in COLLECTIVES else None
+
+
+def collective_spans(events):
+    """The intervals in which a collective of ``(start, end, op name)``
+    events on one line is in flight: a synchronous op's own interval, and
+    for an asynchronous pair the span from the ``-start`` op's start to the
+    end of the next ``-done`` of its kind (a pair cut by the window's edge
+    runs to that edge)."""
+    spans, open_ = [], defaultdict(list)
+    for s, e, name in sorted(events):
+        kind = collective_kind(name)
+        if kind is None:
+            continue
+        if kind.endswith("-start"):
+            open_[kind.removesuffix("-start")].append(s)
+        elif kind.endswith("-done"):
+            starts = open_[kind.removesuffix("-done")]
+            spans.append((starts.pop(0) if starts else -math.inf, e))
+        else:
+            spans.append((s, e))
+    spans += [(s, math.inf) for starts in open_.values() for s in starts]
+    return spans
+
+
+def overlap(a, b) -> float:
+    """Length of the intersection of two unions of intervals."""
+    a, b = merge(a), merge(b)
+    tot, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            tot += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return tot
+
+
 def name_gaps(idle, spans):
     """Sum idle time by the innermost host span covering each gap's
     midpoint (``"host: outside spans"`` where none does). Spans nest, so
@@ -102,10 +161,13 @@ def name_gaps(idle, spans):
     return out
 
 
-def summarize(device_events, host_spans, lo, hi) -> TraceSummary:
+def summarize(device_events, host_spans, lo, hi,
+              chips: int = 1) -> TraceSummary:
     """Numbers of the window ``[lo, hi]`` (seconds on the trace's clock)
-    from ``{device: [(start, end, op name)]}`` and host spans."""
+    from ``{device: [(start, end, op name)]}`` and host spans, averaged
+    over the cell's ``chips``."""
     busy_by_dev, idle_by_name, ops = [], defaultdict(float), defaultdict(float)
+    coll = exposed = 0.0
     for events in device_events.values():
         iv = clip([(s, e) for s, e, _ in events], lo, hi)
         if not iv:
@@ -117,15 +179,22 @@ def summarize(device_events, host_spans, lo, hi) -> TraceSummary:
                   if e > lo and s < hi]
         for name, t in self_times(inside).items():
             ops[name] += t
-    k = max(len(busy_by_dev), 1)
+            if collective_kind(name) is not None:
+                exposed += t
+        coll += overlap(clip(collective_spans(events), lo, hi), iv)
+    if len(busy_by_dev) > chips:
+        raise ValueError(f"{len(busy_by_dev)} devices ran ops in the window "
+                         f"of a cell on {chips} chips")
     top_ops = sorted(ops.items(), key=lambda x: -x[1])[:TOP]
     top_gaps = sorted(idle_by_name.items(), key=lambda x: -x[1])[:TOP]
     return TraceSummary(
         window_s=hi - lo,
-        busy_s=sum(busy_by_dev) / k,
+        busy_s=sum(busy_by_dev) / chips,
         devices=len(busy_by_dev),
-        device_ops=[[n, t / k] for n, t in top_ops],
-        idle_gaps=[[n, t / k] for n, t in top_gaps],
+        device_ops=[[n, t / chips] for n, t in top_ops],
+        idle_gaps=[[n, t / chips] for n, t in top_gaps],
+        collective_s=coll / chips,
+        collective_exposed_s=exposed / chips,
     )
 
 
@@ -180,11 +249,13 @@ def find_xplane(log_dir) -> str:
     return found[-1]
 
 
-def summarize_file(path, window_span: str = "bench.window") -> TraceSummary:
-    """Summary of the window that the host span ``window_span`` marks."""
+def summarize_file(path, window_span: str = "bench.window",
+                   chips: int = 1) -> TraceSummary:
+    """Summary of the window that the host span ``window_span`` marks, over
+    a cell on ``chips`` chips."""
     devices, spans = read_xplane(path)
     win = [(s, e) for s, e, n in spans if n == window_span]
     if not win:
         raise ValueError(f"no host span {window_span!r} in {path}")
     lo, hi = win[-1]
-    return summarize(devices, spans, lo, hi)
+    return summarize(devices, spans, lo, hi, chips)

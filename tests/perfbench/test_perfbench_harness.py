@@ -271,7 +271,7 @@ class _Broken:
     def __init__(self, inner, fault):
         self.inner, self.fault = inner, fault
         self.g, self.criterion = inner.g, inner.criterion
-        self.point_queries = inner.point_queries
+        self.point_queries = getattr(inner, "point_queries", False)
         self.n = inner.n
 
     def init(self, lanes):
