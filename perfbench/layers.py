@@ -11,6 +11,7 @@ import dataclasses
 
 import numpy as np
 
+from perfbench import program_trace
 from perfbench.serve import Window, percentile
 
 
@@ -26,6 +27,7 @@ class Run:
     reference: object  # reference.Reference
     peak: dict  # peaks.PEAKS entry of the device
     chips: int = 1  # chips the cell serves on
+    program: object | None = None  # program_trace.ProgramTrace (--trace 1)
 
 
 def _in_window(run: Run):
@@ -76,11 +78,27 @@ def phases_per_query(run: Run):
     return float(np.mean([r.phases for r in eng]))
 
 
-def sched_host_ms_per_chunk(run: Run):
-    w = run.window
-    if not w.chunks:
-        return None
-    return 1e3 * (w.step_wall_s - w.backend_wall_s) / w.chunks
+def relax_ms_per_phase(run: Run):
+    """Device time under the program's ``sssp.relax`` scope per phase."""
+    return program_trace.relax_device_ms_per_phase(run.program,
+                                                   run.window.trips)
+
+
+def criteria_ms_per_phase(run: Run):
+    """Device time under the settle criterion's scopes per phase."""
+    return program_trace.criteria_device_ms_per_phase(run.program,
+                                                      run.window.trips)
+
+
+def chunk_gap_ms(run: Run):
+    """Device idle between consecutive step executions, per chunk."""
+    return program_trace.chunk_gap_ms(run.program)
+
+
+def round_host_ms_per_chunk(run: Run):
+    """Host time of the program's ``serving.round`` spans, less the waits
+    in ``serving.sync`` and ``serving.take_row``, per dispatched chunk."""
+    return program_trace.round_host_ms_per_chunk(run.program)
 
 
 def device_idle_share(run: Run):

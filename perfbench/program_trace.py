@@ -10,7 +10,13 @@ into the same ``.xplane.pb``, on the same clock:
   ``serving.harvest`` with one ``serving.take_row`` per lane taken);
 - the ``jax.named_scope`` of each device op (``sssp.prime``,
   ``sssp.out_scan``, ``sssp.thresholds``, ``sssp.settle``, ``sssp.relax``,
-  ``sssp.update``), and the program each op ran in.
+  ``sssp.update``), and the program each op ran in;
+- the step program: whatever program ``serving.dispatch`` launches, which
+  is named after the backend (``jit__step_batch_impl`` on one chip,
+  ``jit_step`` over a mesh). The round launches nothing between the
+  dispatch and the ``peek`` of ``serving.sync``, so the step program is
+  the one that each device runs just before each execution of
+  ``jit__peek``, the serving layer's read that every backend shares.
 
 ``jax.profiler.ProfileData`` gives the ops and the ``XLA Modules`` line, but
 not the stats of an op's metadata, where the scope path lies (the ``tf_op``
@@ -32,18 +38,19 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import re
-from collections import defaultdict
+from collections import Counter, defaultdict
 
 from perfbench import trace
 
 SCOPE_PREFIX = "sssp."
 SPAN_PREFIX = "serving."
-STEP_PROGRAM = "jit__step_batch_impl"
-# (host span, the program it runs, whether the host waits for its end)
-CALLS = (("serving.dispatch", STEP_PROGRAM, False),
-         ("serving.sync", "jit__peek", True),
-         ("serving.take_row", "jit__take_row", True))
-MODULES_LINE = "XLA Modules"
+SYNC_PROGRAM = "jit__peek"
+# (host span, the program it runs, whether the host waits for its end);
+# ``serving.dispatch`` runs the step program, found by ``step_program``
+WAITED_CALLS = (("serving.sync", SYNC_PROGRAM, True),
+                ("serving.take_row", "jit__take_row", True))
+MODULES_LINE = trace.MODULES_LINE
+HOST_PREFIXES = (SPAN_PREFIX, "bench.")
 CRITERIA_SCOPES = ("sssp.out_scan", "sssp.thresholds", "sssp.settle")
 RELAX_SCOPE = "sssp.relax"
 HOST_WAITS = ("serving.sync", "serving.take_row")
@@ -231,7 +238,7 @@ def label_ops(events, mods, names: OpNames, lo: float, hi: float):
 @dataclasses.dataclass
 class ProgramTrace:
     window_s: float
-    busy_s: float  # mean over devices with ops
+    busy_s: float  # mean over the cell's chips
     device_s_by_scope: dict  # {sssp scope or program name: self seconds}
     inherited_s: float  # of those under a scope, seconds named by inheritance
     step_runs: int  # step program executions that start in the window
@@ -245,9 +252,19 @@ class ProgramTrace:
     name_collisions: int
 
 
-def _runs(modules, program: str):
+def _runs(modules, program: str | None):
     return sorted((s, e) for s, e, name in modules
                   if split_module(name)[0] == program)
+
+
+def step_program(modules) -> str | None:
+    """The name of the program that ``serving.dispatch`` launches: the one
+    a device runs most often just before an execution of ``jit__peek``;
+    None where the device ran no peek."""
+    names = [split_module(name)[0] for _, _, name in sorted(modules)]
+    before = Counter(a for a, b in zip(names, names[1:])
+                     if b == SYNC_PROGRAM and a != SYNC_PROGRAM)
+    return before.most_common(1)[0][0] if before else None
 
 
 def chunk_gaps(step_runs, busy, lo: float, hi: float) -> float:
@@ -279,7 +296,8 @@ def clock_offset(mods, spans):
     the host waits for it (``serving.sync``, ``serving.take_row``) it ends
     before that span ends."""
     lo, hi = [], []
-    for span, program, waits in CALLS:
+    calls = (("serving.dispatch", step_program(mods), False),) + WAITED_CALLS
+    for span, program, waits in calls:
         runs, host = _runs(mods, program), _ordered(spans, span)
         if not host or len(host) != len(runs):
             continue
@@ -328,10 +346,12 @@ def round_host(spans, lo: float, hi: float) -> list:
     return out
 
 
-def summarize(device_ops, modules, entries, spans, lo, hi) -> ProgramTrace:
+def summarize(device_ops, modules, entries, spans, lo, hi,
+              chips: int = 1) -> ProgramTrace:
     """The program's numbers of the window ``[lo, hi]`` from
     ``{device: [(start, end, op name)]}``, ``{device: [(start, end, module
-    name)]}``, ``{device: op metadata entries}`` and the host spans."""
+    name)]}``, ``{device: op metadata entries}`` and the host spans; device
+    times per chip, averaged over the cell's ``chips``."""
     busy_by_dev, collisions, step_runs, gap, inherited = [], 0, 0, 0.0, 0.0
     launch = ret = 0.0
     offset = None
@@ -353,7 +373,7 @@ def summarize(device_ops, modules, entries, spans, lo, hi) -> ProgramTrace:
         direct = trace.self_times(
             [(s, e, (n, i)) for s, e, n, i in labelled])
         inherited += sum(t for (_, i), t in direct.items() if i)
-        runs = _runs(mods, STEP_PROGRAM)
+        runs = _runs(mods, step_program(mods))
         step_runs = max(step_runs, sum(lo <= s < hi for s, _ in runs))
         gap += chunk_gaps(runs, iv, lo, hi)
         # host spans name the device's gaps and latencies on the host's
@@ -368,57 +388,54 @@ def summarize(device_ops, modules, entries, spans, lo, hi) -> ProgramTrace:
         idle = [(s + shift, e + shift) for s, e in trace.gaps(iv, lo, hi)]
         for name, t in trace.name_gaps(idle, serving).items():
             idle_by_span[name] += t
-    k = max(len(busy_by_dev), 1)
+    if len(busy_by_dev) > chips:
+        raise ValueError(f"{len(busy_by_dev)} devices ran ops in the window "
+                         f"of a cell on {chips} chips")
     return ProgramTrace(
         window_s=hi - lo,
-        busy_s=sum(busy_by_dev) / k,
-        device_s_by_scope={n: t / k for n, t in sorted(
+        busy_s=sum(busy_by_dev) / chips,
+        device_s_by_scope={n: t / chips for n, t in sorted(
             by_scope.items(), key=lambda x: -x[1])},
-        inherited_s=inherited / k,
+        inherited_s=inherited / chips,
         step_runs=step_runs,
-        chunk_gap_s=gap / k,
-        idle_by_program_span={n: t / k for n, t in sorted(
+        chunk_gap_s=gap / chips,
+        idle_by_program_span={n: t / chips for n, t in sorted(
             idle_by_span.items(), key=lambda x: -x[1])},
         round_host_s=round_host(serving, lo, hi),
         dispatches=sum(n == "serving.dispatch" and lo <= s and e <= hi
                        for s, e, n in serving),
-        launch_s=launch / k,
-        return_s=ret / k,
+        launch_s=launch / chips,
+        return_s=ret / chips,
         clock_offset_s=offset,
         name_collisions=collisions,
     )
 
 
-def read_file(path, window_span: str = "bench.window") -> ProgramTrace:
-    """The program's numbers of the window that the host span
-    ``window_span`` marks, from one ``.xplane.pb`` file."""
-    from jax.profiler import ProfileData
+def load(path) -> trace.Xplane:
+    """``trace.load`` keeping the host spans of both reducers: one read of
+    the file serves ``trace.summarize_file`` and ``read_file``."""
+    return trace.load(path, trace.HOST_PREFIXES + HOST_PREFIXES)
 
-    with open(path, "rb") as f:
-        data = f.read()
-    entries = op_metadata(data)
-    pd = ProfileData.from_serialized_xspace(data)
-    device_ops, modules, spans = {}, {}, []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            for line in plane.lines:
-                if line.name not in (trace.OPS_LINE, MODULES_LINE):
-                    continue
-                evs = [(e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9,
-                        e.name) for e in line.events]
-                into = device_ops if line.name == trace.OPS_LINE else modules
-                into.setdefault(plane.name, []).extend(evs)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                spans += [(e.start_ns * 1e-9,
-                           (e.start_ns + e.duration_ns) * 1e-9, e.name)
-                          for e in line.events
-                          if e.name.startswith((SPAN_PREFIX, "bench."))]
+
+def read_file(src, window_span: str = "bench.window",
+              chips: int = 1) -> ProgramTrace:
+    """The program's numbers of the window that the host span
+    ``window_span`` marks, from one ``.xplane.pb`` file (a path, or its
+    ``load``) of a cell on ``chips`` chips."""
+    xp = src if isinstance(src, trace.Xplane) else load(src)
+    entries = op_metadata(xp.data)
+    device_ops, modules = {}, {}
+    for plane, by_line in xp.lines.items():
+        for line, into in ((trace.OPS_LINE, device_ops),
+                           (MODULES_LINE, modules)):
+            if line in by_line:
+                into.setdefault(plane, []).extend(by_line[line])
+    spans = [x for x in xp.host if x[2].startswith(HOST_PREFIXES)]
     win = [(s, e) for s, e, n in spans if n == window_span]
     if not win:
-        raise ValueError(f"no host span {window_span!r} in {path}")
+        raise ValueError(f"no host span {window_span!r} in the trace")
     lo, hi = win[-1]
-    return summarize(device_ops, modules, entries, spans, lo, hi)
+    return summarize(device_ops, modules, entries, spans, lo, hi, chips)
 
 
 # -- per-layer metrics ----------------------------------------------------------

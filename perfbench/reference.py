@@ -20,11 +20,25 @@ rounded to bfloat16, the precision below the configuration's float32.
 """
 from __future__ import annotations
 
+import math
+import multiprocessing
+import os
+from concurrent.futures import ProcessPoolExecutor
+from itertools import repeat
+
 import numpy as np
 import scipy.sparse as sp
 from scipy.sparse.csgraph import dijkstra
 
 ROW_BLOCK = 32  # reference rows per Dijkstra call
+# Graphs of this many arcs or more have their rows solved in a pool of
+# worker processes. The pool takes about 3 s to start its workers and send
+# each the graph; 32 Graph500 rows took 1.9 s in one process and 3.7 s in
+# the pool at 1.8 M arcs (scale 16), 8.3 s and 8.2 s at 7.6 M (scale 18),
+# and 45 s and 18 s at 30 M (scale 20), on an 8-core x86 host
+# (tests/perfbench/time_reference.py).
+POOL_ARCS = 1 << 23
+ROW_WORKERS = min(8, os.cpu_count() or 1)
 F32_TINY = float(np.finfo(np.float32).tiny)
 
 
@@ -68,15 +82,28 @@ class Reference:
         self.out_dst, self.out_w = v, wmin
 
     def rows(self, sources) -> dict[int, np.ndarray]:
-        """float64 distance rows of ``sources``."""
+        """float64 distance rows of ``sources``. Each row is solved on its
+        own, so a row does not depend on the block it is solved in: on a
+        graph of ``POOL_ARCS`` arcs or more the sources are spread over
+        ``ROW_WORKERS`` spawned processes, in blocks of at most
+        ``ROW_BLOCK``."""
         sources = sorted({int(s) for s in sources})
-        out: dict[int, np.ndarray] = {}
-        for lo in range(0, len(sources), ROW_BLOCK):
-            block = sources[lo:lo + ROW_BLOCK]
-            d = dijkstra(self.csr, directed=True, indices=block)
-            d = np.where(d < 1e-300, 0.0, d)
-            out.update(zip(block, d))
-        return out
+        pool = self.csr.nnz >= POOL_ARCS and len(sources) > 1
+        size = ROW_BLOCK
+        if pool:
+            size = min(ROW_BLOCK, math.ceil(len(sources) / ROW_WORKERS))
+        blocks = [sources[lo:lo + size] for lo in range(0, len(sources), size)]
+        if not pool:
+            solved = map(_dijkstra_rows, repeat(self.csr), blocks)
+            return {s: d for block, rows in zip(blocks, solved)
+                    for s, d in zip(block, rows)}
+        with ProcessPoolExecutor(
+                min(ROW_WORKERS, len(blocks)),
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_keep_csr, initargs=(self.csr,)) as ex:
+            return {s: d for block, rows in zip(blocks,
+                                                ex.map(_kept_rows, blocks))
+                    for s, d in zip(block, rows)}
 
     def fixpoint(self, row: np.ndarray, source: int) -> bool:
         """Whether ``row`` is the exact float32 relaxation fixed point."""
@@ -117,6 +144,24 @@ class Reference:
                 front = np.unique(tgt)
             out.update(zip(block.tolist(), d.reshape(block.size, n)))
         return out
+
+
+def _dijkstra_rows(csr, block) -> np.ndarray:
+    """float64 rows of the sources ``block`` over ``csr``."""
+    d = dijkstra(csr, directed=True, indices=block)
+    return np.where(d < 1e-300, 0.0, d)
+
+
+_CSR = None  # a pool worker's graph, sent once when the worker starts
+
+
+def _keep_csr(csr) -> None:
+    global _CSR
+    _CSR = csr
+
+
+def _kept_rows(block) -> np.ndarray:
+    return _dijkstra_rows(_CSR, block)
 
 
 def compare(answers, ref: Reference, rows64: dict[int, np.ndarray]) -> dict:

@@ -9,7 +9,9 @@ The run builds the cell's graph on the host, its vertices relabelled by
 traffic for ``--seconds`` of wall time, waits for the answers due, and
 compares every answer with the benchmark's own reference. ``--trace 1``
 profiles the window and reports the cell's per-layer metrics; ``--trace 0``
-its end-to-end metrics.
+its end-to-end metrics; per-layer readers take the device's ops from the
+trace and the program's own spans and scopes from it too
+(``perfbench/program_trace.py``).
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
@@ -106,7 +108,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     import jax
     import numpy as np
 
-    from perfbench import graphs, layers, reference, serve
+    from perfbench import graphs, layers, program_trace, reference, serve
     from perfbench import trace as trace_mod
     from perfbench.traffic import Traffic
     from repro.compile_cache import use_compile_cache
@@ -133,7 +135,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     inner = make_backend(g, cfg, cell.chips)
     if wrap_backend is not None:
         inner = wrap_backend(inner)
-    backend = serve.TimedBackend(inner, timer.now)
+    backend = serve.TimedBackend(inner)
     traffic = Traffic(cell.traffic, n, np.bincount(src, minlength=n),
                       cfg["structure_seed"], labels)
     t_graph = timer.now() - t_start
@@ -181,15 +183,21 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     checks = reference.compare(answers, ref, rows64)
     checks["unanswered"] = window.unanswered
     ref_s = timer.now() - t_ref
-    summary = None
+    summary = program = program_trace_s = trace_read_s = None
     if trace:
-        summary = trace_mod.summarize_file(trace_mod.find_xplane(trace_dir),
-                                           chips=cell.chips)
+        t_read = timer.now()
+        xplane = program_trace.load(trace_mod.find_xplane(trace_dir))
+        summary = trace_mod.summarize_file(xplane, chips=cell.chips)
+        t_program = timer.now()
+        program = program_trace.read_file(xplane, chips=cell.chips)
+        program_trace_s = timer.now() - t_program
+        trace_read_s = timer.now() - t_read
+        del xplane
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = layers.Run(setup_s=setup_s, window=window, drain_end=drain_end,
                      n=n, lanes=int(cfg["lanes"]), trace=summary,
                      ref_rows=rows64, reference=ref, peak=peak,
-                     chips=cell.chips)
+                     chips=cell.chips, program=program)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = m.read(run)
@@ -225,6 +233,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
                              "max": float(np.max(lat)) if lat else 0.0},
         "gc_in_window": gc_pauses.summary(),
         "reference_s": ref_s,
+        "program_trace_s": program_trace_s,
+        "trace_read_s": trace_read_s,
         "distinct_rows_checked": len(rows64),
     }
     return result, checks, info

@@ -2,9 +2,9 @@
 
 The entry point the window drives is the program's ``ContinuousBatcher``
 over the cell's backend, with the wall clock. ``TimedBackend`` is the
-benchmark's own delegating proxy: it times the calls into the backend and
-puts a named host span around each, so that the scheduler's host time per
-chunk and the device's idle gaps can be told apart.
+benchmark's own delegating proxy: it counts the calls into the backend and
+puts a named host span around each, so that the device's idle gaps can be
+named by what the host was doing.
 """
 from __future__ import annotations
 
@@ -22,18 +22,17 @@ CLOSED_BLOCK = 1024  # closed-loop queries drawn at a time
 
 
 class TimedBackend:
-    """Delegating proxy over an ``EngineBackend``: wall time and call counts
-    of ``step`` / ``peek`` / ``take_row`` / ``reset_lanes``."""
+    """Delegating proxy over an ``EngineBackend``: call counts of ``step`` /
+    ``peek`` / ``take_row`` / ``reset_lanes``, each in a ``backend.*``
+    host span."""
 
     CALLS = ("step", "peek", "take_row", "reset_lanes")
 
-    def __init__(self, inner, clock):
+    def __init__(self, inner):
         self.inner = inner
         self.g = inner.g
         self.criterion = inner.criterion
         self.point_queries = getattr(inner, "point_queries", False)
-        self._clock = clock
-        self.wall = dict.fromkeys(self.CALLS, 0.0)
         self.calls = dict.fromkeys(self.CALLS, 0)
 
     @property
@@ -44,10 +43,8 @@ class TimedBackend:
         return self.inner.init(lanes)
 
     def _timed(self, name, *args, **kw):
-        t0 = self._clock()
         with TraceAnnotation(f"backend.{name}"):
             out = getattr(self.inner, name)(*args, **kw)
-        self.wall[name] += self._clock() - t0
         self.calls[name] += 1
         return out
 
@@ -64,7 +61,7 @@ class TimedBackend:
         return self._timed("reset_lanes", state, sources, **kw)
 
     def snapshot(self):
-        return dict(self.wall), dict(self.calls)
+        return dict(self.calls)
 
 
 @dataclasses.dataclass
@@ -78,8 +75,6 @@ class Window:
     requests: list  # every Request submitted in the window, in order
     answered_in_window: int
     lateness_s: list  # open loop: submit time minus scheduled time
-    step_wall_s: float  # wall inside ContinuousBatcher.step
-    backend_wall_s: float  # wall inside backend calls made by those steps
     chunks: int  # backend.step calls (engine chunks) in the window
     trips: int  # engine phases advanced in the window
     unanswered: int = 0  # set after the drain
@@ -107,8 +102,7 @@ def run_window(server, backend: TimedBackend, traffic: Traffic,
     loop = traffic.params["loop"]
     reqs: list = []
     lateness: list = []
-    step_wall = 0.0
-    wall0, calls0 = backend.snapshot()
+    calls0 = backend.snapshot()
     trips0 = server.metrics.engine_trips
     answered = retired = 0
     if loop == "open":
@@ -127,11 +121,9 @@ def run_window(server, backend: TimedBackend, traffic: Traffic,
         i += 1
 
     def advance():
-        nonlocal step_wall, answered, retired
-        s0 = clock()
+        nonlocal answered, retired
         with TraceAnnotation("bench.step"):
             done = server.step()
-        step_wall += clock() - s0
         answered += sum(r.outcome == "ok" for r in done)
         retired += len(done)
         return done
@@ -170,7 +162,7 @@ def run_window(server, backend: TimedBackend, traffic: Traffic,
                 while not server.idle and clock() < stop:
                     advance()
     t1 = clock()
-    wall1, calls1 = backend.snapshot()
+    calls1 = backend.snapshot()
     if loop == "open":  # arrivals due before the close that the loop passed
         for j in range(i, times.size):
             reqs.append(_submit(server, srcs[j], tgts[j], t0 + times[j]))
@@ -178,8 +170,6 @@ def run_window(server, backend: TimedBackend, traffic: Traffic,
     return Window(
         loop=loop, seconds=t1 - t0, t0=t0, requests=reqs,
         answered_in_window=answered, lateness_s=lateness,
-        step_wall_s=step_wall,
-        backend_wall_s=sum(wall1.values()) - sum(wall0.values()),
         chunks=calls1["step"] - calls0["step"],
         trips=server.metrics.engine_trips - trips0,
     )

@@ -17,7 +17,9 @@
 
 Device planes are named ``/device:<kind>:<i>``; their ops lie on the line
 ``XLA Ops``. Host spans are the events of the host planes whose names
-start with one of ``HOST_PREFIXES``.
+start with one of ``HOST_PREFIXES``. ``load`` reads a file once for every
+reducer (this one and ``perfbench/program_trace.py``), and each reducer
+takes either a path or what ``load`` returned.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ import re
 from collections import defaultdict
 
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PREFIXES = ("bench.", "backend.")
 TOP = 10
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -211,33 +214,58 @@ def op_name(hlo: str) -> str:
     return f"{lhs} = {shape} {m.group(2)}"
 
 
-def read_xplane(path):
-    """``({device plane: [(start, end, op)]}, [(start, end, host span)])``
-    in seconds, from one ``.xplane.pb`` file."""
+@dataclasses.dataclass
+class Xplane:
+    """One ``.xplane.pb`` file, read once: its bytes, the events of each
+    device plane's ``XLA Ops`` and ``XLA Modules`` lines, and the host's
+    events of the prefixes asked for, as ``(start, end, name)`` in seconds
+    with their names as recorded."""
+    data: bytes
+    lines: dict  # {device plane: {line name: [(start, end, name)]}}
+    host: list  # [(start, end, name)], in the file's order
+
+
+def load(path, prefixes=HOST_PREFIXES) -> Xplane:
+    """Read ``path`` and its ``ProfileData`` once, keeping the host events
+    whose names start with one of ``prefixes``."""
     from jax.profiler import ProfileData
 
-    pd = ProfileData.from_file(path)
-    devices, spans, names = {}, [], {}
-    for plane in pd.planes:
+    with open(path, "rb") as f:
+        data = f.read()
+    lines, host = {}, []
+    for plane in ProfileData.from_serialized_xspace(data).planes:
         if plane.name.startswith("/device:"):
-            evs = []
             for line in plane.lines:
-                if line.name != OPS_LINE:
-                    continue
-                for e in line.events:
-                    name = names.get(e.name)
-                    if name is None:
-                        name = names[e.name] = op_name(e.name)
-                    evs.append((e.start_ns * 1e-9,
-                                (e.start_ns + e.duration_ns) * 1e-9, name))
-            if evs:
-                devices[plane.name] = evs
+                if line.name in (OPS_LINE, MODULES_LINE):
+                    lines.setdefault(plane.name, {}).setdefault(
+                        line.name, []).extend(
+                        (e.start_ns * 1e-9,
+                         (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                        for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans += [(e.start_ns * 1e-9,
-                           (e.start_ns + e.duration_ns) * 1e-9, e.name)
-                          for e in line.events
-                          if e.name.startswith(HOST_PREFIXES)]
+                host += [(e.start_ns * 1e-9,
+                          (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                         for e in line.events
+                         if e.name.startswith(prefixes)]
+    return Xplane(data, lines, host)
+
+
+def read_xplane(src):
+    """``({device plane: [(start, end, op)]}, [(start, end, host span)])``
+    in seconds, from one ``.xplane.pb`` file (a path, or its ``load``)."""
+    xp = src if isinstance(src, Xplane) else load(src)
+    devices, names = {}, {}
+    for plane, by_line in xp.lines.items():
+        evs = []
+        for s, e, raw in by_line.get(OPS_LINE, ()):
+            name = names.get(raw)
+            if name is None:
+                name = names[raw] = op_name(raw)
+            evs.append((s, e, name))
+        if evs:
+            devices[plane] = evs
+    spans = [x for x in xp.host if x[2].startswith(HOST_PREFIXES)]
     return devices, spans
 
 
@@ -249,13 +277,13 @@ def find_xplane(log_dir) -> str:
     return found[-1]
 
 
-def summarize_file(path, window_span: str = "bench.window",
+def summarize_file(src, window_span: str = "bench.window",
                    chips: int = 1) -> TraceSummary:
     """Summary of the window that the host span ``window_span`` marks, over
-    a cell on ``chips`` chips."""
-    devices, spans = read_xplane(path)
+    a cell on ``chips`` chips, from a path or its ``load``."""
+    devices, spans = read_xplane(src)
     win = [(s, e) for s, e, n in spans if n == window_span]
     if not win:
-        raise ValueError(f"no host span {window_span!r} in {path}")
+        raise ValueError(f"no host span {window_span!r} in the trace")
     lo, hi = win[-1]
     return summarize(devices, spans, lo, hi, chips)

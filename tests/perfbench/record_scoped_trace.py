@@ -26,14 +26,13 @@ def main(out_dir: str) -> int:
 
     from perfbench import graphs, program_trace, serve, trace
     from repro.core.graph import from_coo
-    from repro.obs import timer
     from repro.serving import ContinuousBatcher, StaticBackend
 
     cfg = {"generator": "graph500", "structure_seed": 20261016, "scale": 11,
            "edgefactor": 16, "a": 0.57, "b": 0.19, "c": 0.19}
     src, dst, w, n, _ = graphs.build(cfg, 7)
     g = from_coo(src, dst, w, n)
-    backend = serve.TimedBackend(StaticBackend(g, layout="sliced"), timer.now)
+    backend = serve.TimedBackend(StaticBackend(g, layout="sliced"))
     server = ContinuousBatcher(g, lanes=4, backend=backend)
     roots = [int(v) for v in src[:: max(1, src.size // 8)][:8]]
     server.submit(roots[0])

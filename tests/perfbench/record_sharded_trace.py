@@ -27,7 +27,6 @@ def main(out_dir: str) -> int:
     from perfbench.run import make_backend
     from perfbench.spec import load_cell
     from repro.core.graph import from_coo
-    from repro.obs import timer
     from repro.serving import ContinuousBatcher
 
     cell = load_cell("tiny-g500-sharded", HERE / "data")
@@ -37,8 +36,7 @@ def main(out_dir: str) -> int:
         return 2
     src, dst, w, n, _ = graphs.build(cell.config, 7)
     g = from_coo(src, dst, w, n)
-    backend = serve.TimedBackend(make_backend(g, cell.config, cell.chips),
-                                 timer.now)
+    backend = serve.TimedBackend(make_backend(g, cell.config, cell.chips))
     server = ContinuousBatcher(g, lanes=int(cell.config["lanes"]),
                                backend=backend, phases_per_step=8)
     for s in (0, 1):  # compiles outside the trace, fresh and sharded state

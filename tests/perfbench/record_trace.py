@@ -20,14 +20,13 @@ def main(out_dir: str) -> int:
 
     from perfbench import graphs, serve, trace
     from repro.core.graph import from_coo
-    from repro.obs import timer
     from repro.serving import ContinuousBatcher, StaticBackend
 
     cfg = {"generator": "grid_road", "structure_seed": 7, "side": 16,
            "diag_frac": 0.05}
     src, dst, w, n, _ = graphs.build(cfg, 7)
     g = from_coo(src, dst, w, n)
-    backend = serve.TimedBackend(StaticBackend(g), timer.now)
+    backend = serve.TimedBackend(StaticBackend(g))
     server = ContinuousBatcher(g, lanes=4, backend=backend, phases_per_step=8)
     server.submit(0)
     server.drain()  # compiles outside the trace

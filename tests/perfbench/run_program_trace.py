@@ -2,9 +2,9 @@
 
     python3 tests/perfbench/run_program_trace.py --workload NAME --seconds S SEED...
 
-Each seed runs the cell as ``perfbench/run.py --trace 1`` does. The harness
-reduces its trace and then deletes it, so this script reads the same
-``.xplane.pb`` with ``perfbench/program_trace.py`` at that moment too. It
+Each seed runs the cell as ``perfbench/run.py --trace 1`` does, and keeps
+what the harness's ``perfbench/program_trace.py`` read of the trace before
+the harness deleted it. It
 prints one JSON line per seed: the cell's traced result (per-layer metrics,
 ``correct``), the queries answered per second of the traced window, and the
 program's numbers (device time by ``sssp.*`` scope, idle time by innermost
@@ -39,7 +39,6 @@ def main(argv=None) -> int:
     import jax
 
     from perfbench import program_trace
-    from perfbench import trace as trace_mod
     from perfbench.peaks import PEAKS
     from perfbench.run import TRACE_DIR, NoChip, check_machine, run_cell
     from perfbench.spec import load_cell
@@ -56,20 +55,20 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"run_program_trace: {e}", file=sys.stderr)
         return 2
-    summarize_file = trace_mod.summarize_file
+    read_file = program_trace.read_file
     read = {}
 
-    def summarize_and_read(path, *a, **kw):
-        read["program"] = program_trace.read_file(path)
+    def read_and_keep(path, *a, **kw):
+        read["program"] = read_file(path, *a, **kw)
         with open(path, "rb") as f:
             meta = program_trace.op_metadata(f.read())
         read["op_metadata"] = {
             plane: {"entries": len(e),
                     "with_tf_op": sum(t is not None for _, _, t in e)}
             for plane, e in meta.items()}
-        return summarize_file(path, *a, **kw)
+        return read["program"]
 
-    trace_mod.summarize_file = summarize_and_read
+    program_trace.read_file = read_and_keep
     for seed in args.seeds:
         result, _, info = run_cell(
             cell, seed, args.seconds, True, time.perf_counter(),

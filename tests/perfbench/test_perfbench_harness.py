@@ -165,8 +165,7 @@ def test_closed_loop_ramps_up_and_closes_answered():
     """The closed loop reaches its outstanding count one request per round,
     and its window closes once everything it sent is answered."""
     server = _FakeServer()
-    backend = type("B", (), {"snapshot": lambda self: (
-        {"step": 0.0}, {"step": 0})})()
+    backend = type("B", (), {"snapshot": lambda self: {"step": 0}})()
     traffic = Traffic({"loop": "closed", "outstanding": 4, "pool": "all",
                        "support": None, "zipf_s": None}, 50, np.ones(50), 3)
     w = serve.run_window(server, backend, traffic, 20.0, server.clock)
@@ -210,6 +209,28 @@ def test_reference_on_a_hand_graph():
     bad = reference.compare([(0, 3, np.float32([0, 0.5, 0.75, 1.0]))],
                             ref, {0: row})
     assert bad["reach_mismatch"] == 1
+
+
+@pytest.mark.parametrize("config", ["tiny-g500", "tiny-road",
+                                    "tiny-g500-sharded"])
+def test_pooled_reference_rows_are_the_sequential_rows(config, monkeypatch):
+    """Rows solved in the worker pool of a large graph are, bit for bit,
+    the rows solved in this process in blocks of ``ROW_BLOCK``."""
+    cfg = load_cell(next(w["name"] for w in json.loads(
+        (DATA / "BENCHMARK.json").read_text())["workloads"]
+        if w["config"] == config), DATA).config
+    s, d, w, n, _ = graphs.build(cfg, BIG_SEED)
+    ref = reference.Reference(s, d, w, n)
+    sources = np.nonzero(np.bincount(s, minlength=n))[0][::3].tolist()
+    assert len(sources) > reference.ROW_BLOCK  # more than one block
+    sequential = ref.rows(sources)
+    monkeypatch.setattr(reference, "POOL_ARCS", 0)
+    monkeypatch.setattr(reference, "ROW_WORKERS", 3)
+    pooled = ref.rows(sources + sources[:5])
+    assert list(pooled) == list(sequential) == sorted(sources)
+    for x in sources:
+        assert pooled[x].dtype == np.float64
+        assert np.array_equal(pooled[x], sequential[x])
 
 
 def test_bf16_rounding_matches_ml_dtypes():
@@ -263,6 +284,23 @@ def test_tiny_cell_end_to_end(cell, tmp_path):
     assert "answered_count" in traced["metrics"]
     assert {"busy_s", "window_s"} <= set(traced["device"])
     assert set(traced["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_traced_run_reads_the_programs_own_trace(tmp_path):
+    """A ``--trace 1`` run reads the program's spans from its trace before
+    deleting it: the fixture cell's ``round_host_ms_per_chunk.analytics``
+    reads the ``serving.round`` spans, and the read's wall time is in the
+    ``info``; an untraced run reads no trace."""
+    result, _, info = _tiny_run("tiny-road-open", trace=True,
+                                tmp_path=tmp_path / "t")
+    assert result["correct"], result["checks"]
+    host = result["metrics"]["round_host_ms_per_chunk.analytics"]
+    assert host["unit"] == "ms" and host["value"] > 0
+    assert 0 < info["program_trace_s"] < info["trace_read_s"]
+    assert not (tmp_path / "t").exists()
+    _, _, untraced = _tiny_run("tiny-road-open")
+    assert untraced["program_trace_s"] is None
+    assert untraced["trace_read_s"] is None
 
 
 class _Broken:

@@ -4,11 +4,12 @@ the arithmetic on hand-made intervals, and two traces recorded on a TPU v5e,
 one from before the program had spans and scopes and one after
 (``tests/perfbench/record_scoped_trace.py``)."""
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
+from perfbench import layers, trace
 from perfbench import program_trace as pt
-from perfbench import trace
 
 DATA = Path(__file__).resolve().parent / "data"
 PLAIN = DATA / "tpu_trace.xplane.pb"
@@ -210,6 +211,22 @@ def test_trace_from_before_the_spans_and_scopes_reads_empty():
                                         "jit_convert_element_type"}
 
 
+@pytest.mark.parametrize("name", ["tpu_trace.xplane.pb",
+                                  "tpu_trace_scoped.xplane.pb",
+                                  "tpu_trace_sharded.xplane.pb"])
+def test_one_load_serves_both_reducers(name):
+    """A file read once by ``program_trace.load`` gives both reducers what
+    each reads from the path itself, to the bit."""
+    path = str(DATA / name)
+    chips = 4 if "sharded" in name else 1
+    xplane = pt.load(path)
+    assert trace.read_xplane(xplane) == trace.read_xplane(path)
+    assert trace.summarize_file(xplane, chips=chips) == trace.summarize_file(
+        path, chips=chips)
+    assert pt.read_file(xplane, chips=chips) == pt.read_file(path,
+                                                             chips=chips)
+
+
 def test_recorded_trace_with_spans_and_scopes():
     p = pt.read_file(str(SCOPED))
     scoped = {k: t for k, t in p.device_s_by_scope.items()
@@ -236,3 +253,85 @@ def test_recorded_trace_with_spans_and_scopes():
     assert 0 < p.launch_s and 0 < p.return_s
     assert pt.relax_device_ms_per_phase(p, 1) > 0
     assert pt.round_host_ms_per_chunk(p) > 0
+
+
+@pytest.mark.parametrize("step,others", [
+    (STEP, ["jit__reset_lanes_impl", "jit_convert_element_type"]),
+    ("jit_step", ["jit__reset_sharded_impl", "jit_convert_element_type"]),
+])
+def test_step_program_is_what_the_dispatch_launches(step, others):
+    """The program a device runs just before each peek, whatever its name;
+    the round's other programs (a reset launched in admission, the take_row
+    of a harvest) run elsewhere in the order."""
+    reset, convert = others
+    order = [reset, convert, step, "jit__peek", convert, "jit__take_row",
+             step, "jit__peek", reset, step, "jit__peek"]
+    mods = [(float(i), i + 0.5, f"{name}({i})")
+            for i, name in enumerate(order)]
+    assert pt.step_program(mods[::-1]) == step  # in order of start
+    assert pt.step_program(mods[:3]) is None  # no peek: nothing launched
+    assert pt.step_program([]) is None
+
+
+def test_scoped_trace_reads_as_before():
+    """The one-chip trace reads to the bit what it read while the step
+    program was found by its name, ``jit__step_batch_impl``."""
+    p = pt.read_file(str(SCOPED))
+    assert (p.step_runs, p.dispatches) == (7, 7)
+    assert p.window_s == 0.10115010100000002
+    assert p.busy_s == 0.06809366100000003
+    assert p.chunk_gap_s == 0.02715859399999998
+    assert p.launch_s == 0.006644968500000077
+    assert p.return_s == 0.012913495499999983
+    assert p.clock_offset_s == (0.0008784970000000059, 0.0020557000000000075)
+    assert p.device_s_by_scope["sssp.relax"] == 0.0643574029999997
+    assert p.device_s_by_scope[STEP] == 0.0013845540000002043
+    assert p.idle_by_program_span["serving.sync"] == 0.017831995000000017
+    assert sum(p.round_host_s) == sum([
+        0.0023795299999999978, 0.002027710000000016, 0.0017118700000000042,
+        0.0016849800000000026, 0.0015063599999999983, 0.0016721100000000044,
+        0.001103948999999993])
+    assert pt.chunk_gap_ms(p) == 3.87979914285714
+    assert pt.relax_device_ms_per_phase(p, 100) == 0.643574029999997
+    assert pt.criteria_device_ms_per_phase(p, 100) == 0.007168620000002
+    plain = pt.read_file(str(PLAIN))
+    assert (plain.step_runs, plain.chunk_gap_s) == (15, 0.05419009300000001)
+
+
+def test_device_times_are_averaged_over_the_cells_chips():
+    """A chip that ran no op in the window counts as idle, as in
+    ``trace.summarize``; more chips with ops than the cell has is an
+    error."""
+    ops = {"/device:TPU:0": [(0.0, 2.0, "a"), (4.0, 5.0, "a")]}
+    modules = {"/device:TPU:0": [(0.0, 2.0, f"{STEP}(5)"),
+                                 (2.0, 2.5, "jit__peek(6)"),
+                                 (4.0, 5.0, f"{STEP}(5)")]}
+    one = pt.summarize(ops, modules, {}, [], 0.0, 10.0)
+    two = pt.summarize(ops, modules, {}, [], 0.0, 10.0, chips=2)
+    assert one.step_runs == two.step_runs == 2
+    assert one.busy_s == 3.0 and two.busy_s == 1.5
+    assert one.chunk_gap_s == 2.0 and two.chunk_gap_s == 1.0
+    assert one.device_s_by_scope == {STEP: 3.0}
+    assert two.device_s_by_scope == {STEP: 1.5}
+    ops["/device:TPU:1"] = ops["/device:TPU:0"]
+    with pytest.raises(ValueError, match="2 devices ran ops"):
+        pt.summarize(ops, modules, {}, [], 0.0, 10.0)
+
+
+@pytest.mark.parametrize("reader,read", [
+    ("relax_ms_per_phase", lambda p: pt.relax_device_ms_per_phase(p, 50)),
+    ("criteria_ms_per_phase",
+     lambda p: pt.criteria_device_ms_per_phase(p, 50)),
+    ("chunk_gap_ms", pt.chunk_gap_ms),
+    ("round_host_ms_per_chunk", pt.round_host_ms_per_chunk),
+])
+def test_layer_readers_read_the_programs_trace(reader, read):
+    """The per-layer readers over a run's program trace and the phases of
+    its window; an untraced run (no program trace) reads nothing."""
+    p = pt.read_file(str(SCOPED))
+    window = SimpleNamespace(trips=50)
+    got = getattr(layers, reader)(SimpleNamespace(program=p, window=window))
+    assert got is not None and got > 0
+    assert got == read(p)
+    assert getattr(layers, reader)(
+        SimpleNamespace(program=None, window=window)) is None
