@@ -40,6 +40,7 @@ from repro.kernels.ell_key_min import ell_key_min, ell_key_min_batch
 from repro.kernels.ell_relax import ell_relax, ell_relax_batch
 from repro.kernels.ell_relax_keys import (
     _merge_parts,
+    _pad_idx,
     ell_gather_min_batch,
     ell_keys_dep_batch,
     ell_relax_keys_batch,
@@ -54,6 +55,20 @@ from repro.kernels.frontier_crit import (
 )
 
 INF = jnp.inf
+
+# A bucket's gather writes its slots as f32[slots, V·B] with V·B (keys times
+# serving lanes) in the minor dimension, which a TPU (8, 128) tile pads to a
+# multiple of 128 lanes: with 8 lanes each slot takes 512 B. Buckets of more
+# slots than a slab of this many bytes holds run as a loop over row tiles
+# (``_gather_min_tiled``), so the slab stays in VMEM and never goes to HBM.
+_TILE_SLAB_BYTES = 16 * 2**20
+
+
+def _tile_rows(width: int, lanes: int) -> int:
+    """Rows of a ``width``-wide bucket per tile whose padded slab of
+    ``lanes`` (V·B) lanes per slot fits ``_TILE_SLAB_BYTES``."""
+    slot_bytes = 4 * 128 * -(-lanes // 128)
+    return max(1, _TILE_SLAB_BYTES // (width * slot_bytes))
 
 
 def _is_sliced(ell) -> bool:
@@ -184,6 +199,9 @@ def gather_min_batch_sliced(
         return ell_sliced_gather_min_batch(
             vecs, sliced, interpret=True)  # repro: allow(hardcoded-interpret)
     v, b, n = vecs.shape
+    # the XLA form's gather source, padded with the +inf sentinel column
+    # once for every bucket and tile
+    src = None if use_pallas else _pad_idx(vecs, n + 1)
     parts = []
     for s in sliced.slices:
         if s.rows.shape[0] == 0:
@@ -195,8 +213,8 @@ def gather_min_batch_sliced(
                 # with the widest and spills the gather source to HBM for
                 # the next (on a v5e at Graph500 scale 16, the 128-wide
                 # bucket's gather then takes 4.2 ms per phase, not 0.9)
-                vecs, _ = jax.lax.optimization_barrier((vecs, parts[-1]))
-            parts.append(kref.ell_gather_min_batch_ref(vecs, s.cols, s.ws))
+                src, _ = jax.lax.optimization_barrier((src, parts[-1]))
+            parts.append(_gather_min_tiled(src, s.cols, s.ws))
             continue
         br = block_rows
         if br is None:
@@ -208,6 +226,33 @@ def gather_min_batch_sliced(
             vecs, s.cols, s.ws, block_rows=br, interpret=interpret
         ))
     return _merge_parts(parts, sliced.merge_heavy, sliced.merge_first, (v, b))
+
+
+def _gather_min_tiled(src, cols, ws):
+    """``kref.ell_gather_min_batch_ref`` over row tiles of ``_tile_rows``
+    rows, from the gather source ``src`` (V, B, n + 1) whose column n is
+    +inf: each tile gathers, adds ``ws`` and takes its row-min before the
+    next, so no slab outgrows VMEM. The last tile is padded with the
+    sentinel column n, and the min is exact: bit-identical rows."""
+    v, b, n = src.shape[0], src.shape[1], src.shape[2] - 1
+
+    def tile(cw):
+        c, w = cw
+        # columns lie in [0, n]: clipping reads what the oracle's default
+        # (fill) mode reads, without a bounds mask over every slot
+        g = jnp.take(src, c, axis=2, mode="clip")
+        return jnp.min(g + w[None, None], axis=-1)
+
+    r, d = cols.shape
+    rows = _tile_rows(d, v * b)
+    if r <= rows:
+        return tile((cols, ws))
+    tiles = -(-r // rows)
+    pad = ((0, tiles * rows - r), (0, 0))
+    cols = jnp.pad(cols, pad, constant_values=n).reshape(tiles, rows, d)
+    ws = jnp.pad(ws, pad).reshape(tiles, rows, d)
+    out = jax.lax.map(tile, (cols, ws))
+    return jnp.moveaxis(out, 0, 2).reshape(v, b, tiles * rows)[..., :r]
 
 
 def static_thresholds_batch(

@@ -2,6 +2,7 @@
 produce bit-identical engine results to the padded layout (f32 min is exact,
 so slicing is a pure layout decision), plus the builders' structural
 invariants and the memoisation satellites."""
+import jax
 import numpy as np
 import pytest
 
@@ -23,8 +24,10 @@ from repro.core.static_engine import (
     step_batch,
 )
 from repro.graphs import grid_road, kronecker, uniform_gnp
+from repro.kernels import ops
 from repro.kernels.ell_relax_keys import _merge_parts
 from repro.kernels.ops import gather_min_batch_sliced
+from repro.kernels.ref import ell_gather_min_batch_ref
 
 # the "property" sweep: bucket boundaries x split thresholds, including
 # degenerate single-bucket and aggressive row-splitting configurations
@@ -113,6 +116,52 @@ def test_merge_plan_without_split_vertices(make):
     np.testing.assert_array_equal(
         np.asarray(run_phased_static_batch(g, srcs, ell=se).dist),
         np.asarray(run_phased_static_batch(g, srcs).dist))
+
+
+@pytest.mark.parametrize("v", [1, 3])
+@pytest.mark.parametrize("tiles", ["below", "one", "ragged"])
+@pytest.mark.parametrize("width", [8, 32, 128, 512])
+def test_tiled_gather_min_is_the_whole_gather_bit_for_bit(width, tiles, v):
+    """A bucket's gather-min in row tiles of ``_tile_rows`` rows returns
+    the one-gather oracle's rows bit for bit: below one tile, exactly one,
+    and several with a ragged tail (sentinel-padded), with sentinel
+    columns, an all-+inf lane in every vector and, for V = 3, an all-+inf
+    vector."""
+    n = 300
+    per_tile = ops._tile_rows(width, v * 8)
+    r = {"below": per_tile // 2 + 1, "one": per_tile,
+         "ragged": 2 * per_tile + per_tile // 3 + 1}[tiles]
+    rng = np.random.default_rng(width * 10 + v)
+    cols = rng.integers(0, n, (r, width)).astype(np.int32)
+    sentinel = rng.random((r, width)) < 0.25
+    cols[sentinel] = n
+    ws = rng.random((r, width)).astype(np.float32)
+    ws[sentinel & (rng.random((r, width)) < 0.5)] = np.inf
+    vecs = rng.random((v, 8, n)).astype(np.float32)
+    vecs[rng.random(vecs.shape) < 0.3] = np.inf
+    vecs[:, 0] = np.inf
+    if v == 3:
+        vecs[-1] = np.inf
+    want = np.asarray(ell_gather_min_batch_ref(vecs, cols, ws))
+    src = ops._pad_idx(vecs, n + 1)
+    got = np.asarray(jax.jit(ops._gather_min_tiled)(src, cols, ws))
+    assert got.shape == want.shape == (v, 8, r)
+    assert got.tobytes() == want.tobytes()
+    assert np.isinf(got[:, 0]).all() and np.isfinite(got).any()
+
+
+@pytest.mark.parametrize("lanes,slot_bytes",
+                         [(1, 512), (8, 512), (128, 512), (129, 1024),
+                          (192, 1024), (384, 1536)])
+def test_tile_rows_keep_the_padded_slab_to_its_bytes(lanes, slot_bytes):
+    """A tile's rows follow the slab's padded bytes per slot (V·B lanes
+    padded to a multiple of 128), so every caller's slab stays within
+    ``_TILE_SLAB_BYTES``, and a bucket too wide for one row still gets one."""
+    for width in (8, 32, 128, 512):
+        rows = ops._tile_rows(width, lanes)
+        assert rows * width * slot_bytes <= ops._TILE_SLAB_BYTES
+        assert (rows + 1) * width * slot_bytes > ops._TILE_SLAB_BYTES
+    assert ops._tile_rows(ops._TILE_SLAB_BYTES, lanes) == 1
 
 
 def test_sliced_stepper_chunking_and_reset():
